@@ -4,6 +4,8 @@
         [--bench tpch|tpcds|both] [--oracle] [--full]
 
 Prints one CSV block per benchmark and writes JSON to results/bench/.
+Exits non-zero when any benchmark it ran failed (the others still run and
+print).
 
 Benchmarks → paper artifacts:
   model_accuracy    Table 3      GTN+regressor WMAPE/P50/P90/Corr/Xput
@@ -31,7 +33,10 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 from typing import Callable, Dict, List
+
+from repro.compile_cache import setup_compile_cache
 
 from .common import save_bench
 
@@ -56,6 +61,8 @@ def main() -> None:
                     help="use simulator-on-estimates objectives (no models)")
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+
+    setup_compile_cache()
 
     benches = ["tpch", "tpcds"] if args.bench == "both" else [args.bench]
     use_model = not args.oracle
@@ -128,6 +135,7 @@ def main() -> None:
             rows = registry[name]()
         except Exception as exc:  # noqa: BLE001 — report and continue
             print(f"\n=== {name} === FAILED: {type(exc).__name__}: {exc}")
+            traceback.print_exc()
             summary[name] = "failed"
             continue
         _print_rows(name, rows)
@@ -136,6 +144,8 @@ def main() -> None:
     print("\n=== summary ===")
     for k, v in summary.items():
         print(f"{k}: {v}")
+    if "failed" in summary.values():
+        sys.exit(1)
 
 
 if __name__ == "__main__":

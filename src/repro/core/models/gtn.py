@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from .features import LAPPE_K, OP_FEAT_DIM
-from .nn import Params, dense, dense_init, layernorm, layernorm_init, mlp, mlp_init
+from .nn import (MATMUL_PRECISION, Params, dense, dense_init, layernorm,
+                 layernorm_init, mlp, mlp_init)
 
 __all__ = ["GTNConfig", "gtn_init", "gtn_apply", "gtn_apply_batch"]
 
@@ -68,11 +69,14 @@ def gtn_apply(p: Params, cfg: GTNConfig, X: jnp.ndarray, pe: jnp.ndarray,
         hn = layernorm(lp["ln1"], h)
         qkv = dense(lp["qkv"], hn).reshape(N, 3, cfg.n_heads, dh)
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]        # (N, H, dh)
-        logits = jnp.einsum("nhd,mhd->hnm", q, k) / jnp.sqrt(dh)
-        struct = jnp.einsum("nmf,hf->hnm", bias, lp["bias"])
+        logits = jnp.einsum("nhd,mhd->hnm", q, k,
+                            precision=MATMUL_PRECISION) / jnp.sqrt(dh)
+        struct = jnp.einsum("nmf,hf->hnm", bias, lp["bias"],
+                            precision=MATMUL_PRECISION)
         logits = logits + struct + attn_mask[None, :, :]
         w = jax.nn.softmax(logits, axis=-1)
-        ctx = jnp.einsum("hnm,mhd->nhd", w, v).reshape(N, cfg.d_model)
+        ctx = jnp.einsum("hnm,mhd->nhd", w, v,
+                         precision=MATMUL_PRECISION).reshape(N, cfg.d_model)
         h = h + dense(lp["out"], ctx)
         hn = layernorm(lp["ln2"], h)
         h = h + mlp(lp["ffn"], hn)

@@ -2,6 +2,11 @@
 
 Parameters are nested dicts of jnp arrays ("pytrees").  Everything here is
 jit/vmap-friendly and deterministic given a PRNGKey.
+
+Matmuls run at :data:`MATMUL_PRECISION` (float32 operands kept float32).
+The TPU's default precision rounds float32 operands to bfloat16, so
+without it the same model would predict different objectives on the chip
+than on a CPU host.
 """
 from __future__ import annotations
 
@@ -14,7 +19,10 @@ import numpy as np
 Params = Dict[str, Any]
 
 __all__ = ["dense_init", "dense", "mlp_init", "mlp", "layernorm_init",
-           "layernorm", "adamw_init", "adamw_update", "tree_l2"]
+           "layernorm", "adamw_init", "adamw_update", "tree_l2",
+           "MATMUL_PRECISION"]
+
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def dense_init(key: jax.Array, d_in: int, d_out: int,
@@ -24,7 +32,7 @@ def dense_init(key: jax.Array, d_in: int, d_out: int,
 
 
 def dense(p: Params, x: jnp.ndarray) -> jnp.ndarray:
-    return x @ p["w"] + p["b"]
+    return jnp.matmul(x, p["w"], precision=MATMUL_PRECISION) + p["b"]
 
 
 def mlp_init(key: jax.Array, dims: Sequence[int]) -> Params:

@@ -64,7 +64,19 @@ class ModelConfig:
 TARGET_EPS = 1e-3
 
 
-def pow2_bucket(n: int, lo: int = 64) -> int:
+# XLA's CPU backend rounds a matmul of fewer than 64 rows differently from a
+# larger one, so a row's result would depend on how many rows share its
+# dispatch.  Every regressor dispatch carries at least this many rows.
+MIN_DISPATCH_ROWS = 64
+
+# Node rows per GTN dispatch.  Every embedding dispatch of a model has this
+# one shape (graphs chunked, the last chunk padded): on the TPU a graph's
+# embedding differs in its last bits between batch shapes, so a per-query
+# solve and a served micro-batch would otherwise disagree.
+EMBED_CHUNK_NODES = 256
+
+
+def pow2_bucket(n: int, lo: int = MIN_DISPATCH_ROWS) -> int:
     """Smallest power of two ≥ max(n, lo).
 
     Batched inference pads its row axis to these buckets so a serving
@@ -133,10 +145,6 @@ class PerfModel:
             return mlp(p["reg"], x)
 
         self._head = jax.jit(_head_fn)
-        # Padded batches are throwaway buffers: donate them on accelerators
-        # (XLA reuses the space for the activations); CPU does not support
-        # donation, so the plain variant is kept for it.
-        self._head_donated = jax.jit(_head_fn, donate_argnums=(1, 2, 3))
         self._embed_batch = _embed_batch
 
     # -- forward -------------------------------------------------------------
@@ -170,38 +178,33 @@ class PerfModel:
         return self._fp
 
     # -- inference -----------------------------------------------------------
+    def _emb_key(self, query: Query, sq_id: Optional[int]) -> tuple:
+        # repro: allow[RP004] id(query) only scopes the process-local embedding memo to one live Query object (qid alone can recur with different stats); the memo is never snapshotted and embeddings do not depend on the id value
+        return (id(query), query.qid, sq_id, self.cfg.kind)
+
     def embed(self, query: Query, sq_id: Optional[int] = None) -> np.ndarray:
         """Cached GTN embedding for a subQ group or whole plan."""
-        # repro: allow[RP004] id(query) only scopes the process-local embedding memo to one live Query object (qid alone can recur with different stats); the memo is never snapshotted and embeddings do not depend on the id value
-        key = (id(query), query.qid, sq_id, self.cfg.kind)
+        key = self._emb_key(query, sq_id)
         if key not in self._emb_cache:
-            if self.cfg.kind in ("subq", "qs"):
-                g = featurize_subq(query, sq_id, use_est=self.cfg.use_est,
-                                   n_pad=self.cfg.pad)
-            else:
-                g = featurize_plan(query, use_est=True, n_pad=self.cfg.pad)
-            gb = batch_graphs([g])
-            emb = self._embed_batch(self.params, gb.X, gb.pe, gb.bias,
-                                    gb.mask)
-            self._emb_cache[key] = np.asarray(emb[0])
+            self.embed_many([(query, sq_id)])
         return self._emb_cache[key]
 
     def embed_many(self, pairs: Sequence[Tuple[Query, Optional[int]]]) -> None:
         """Fill the embedding cache for many (query, sq_id) pairs at once.
 
-        One padded GTN dispatch replaces the per-subQ batch-of-one calls of
-        :meth:`embed` — the cold-path hotspot of a model-backed micro-batch
-        solve.  The batch axis is padded to a power-of-two bucket (replicas
-        of the first graph, sliced off afterwards) so varying batch sizes
-        reuse a small fixed set of compiled signatures.  Per-row outputs are
-        identical to :meth:`embed`'s: row j of a padded batch equals the
-        batch-of-one embedding of graph j.
+        Chunked GTN dispatches replace per-subQ calls — the cold-path
+        hotspot of a model-backed micro-batch solve.  Every dispatch holds
+        the same number of graphs (``EMBED_CHUNK_NODES`` node rows; the last
+        chunk is padded with replicas of its first graph, sliced off
+        afterwards), so one compiled signature serves every batch and an
+        embedding does not depend on how many graphs share its dispatch.
+        Every chunk is dispatched before any result is read back, so the
+        host builds the next chunk while the device runs the last.
         """
         todo = []
         seen = set()
         for query, sq_id in pairs:
-            # repro: allow[RP004] same process-local memo key as `embed` (see above); replay-invariant because only membership is observable, never the id value
-            key = (id(query), query.qid, sq_id, self.cfg.kind)
+            key = self._emb_key(query, sq_id)
             if key in self._emb_cache or key in seen:
                 continue
             seen.add(key)
@@ -213,15 +216,20 @@ class PerfModel:
             todo.append((key, g))
         if not todo:
             return
-        n = len(todo)
-        b = pow2_bucket(n, lo=8)
-        graphs = [g for _, g in todo] + [todo[0][1]] * (b - n)
-        gb = batch_graphs(graphs)
+        b = max(1, EMBED_CHUNK_NODES // self.cfg.pad)
         self.embed_buckets.add(b)
-        emb = np.asarray(self._embed_batch(self.params, gb.X, gb.pe,
-                                           gb.bias, gb.mask))
-        for j, (key, _) in enumerate(todo):
-            self._emb_cache[key] = emb[j]
+        chunks, outs = [], []
+        for off in range(0, len(todo), b):
+            chunk = todo[off:off + b]
+            graphs = [g for _, g in chunk] + [chunk[0][1]] * (b - len(chunk))
+            gb = batch_graphs(graphs)
+            chunks.append(chunk)
+            outs.append(self._embed_batch(self.params, gb.X, gb.pe, gb.bias,
+                                          gb.mask))
+        for chunk, out in zip(chunks, outs):
+            emb = np.asarray(out)
+            for j, (key, _) in enumerate(chunk):
+                self._emb_cache[key] = emb[j]
 
     # -- target transform ------------------------------------------------------
     def to_z(self, y: np.ndarray) -> np.ndarray:
@@ -239,17 +247,19 @@ class PerfModel:
         ``emb`` is one cached embedding (d,) broadcast over the rows, or a
         per-row (n, d) stack — the serving layer fuses re-scoring requests
         from different (query, stage) pairs into one call this way.
+
+        Dispatches through :meth:`predict_rows`, so its rows equal the same
+        rows inside any fused batch (see ``MIN_DISPATCH_ROWS``).
         """
         theta = np.asarray(theta, np.float32)
         n = theta.shape[0]
+        nond = np.asarray(nond, np.float32)
         if nond.ndim == 1:
             nond = np.broadcast_to(nond, (n, nond.shape[0]))
         emb = np.asarray(emb, np.float32)
         embb = emb if emb.ndim == 2 \
             else np.broadcast_to(emb, (n, emb.shape[0]))
-        z = self._head(self.params, embb, theta,
-                       np.asarray(nond, np.float32))
-        return self.from_z(np.asarray(z))
+        return self.predict_rows(embb, theta, nond)
 
     def predict_rows(self, emb: np.ndarray, theta: np.ndarray,
                      nond: np.ndarray) -> np.ndarray:
@@ -258,17 +268,16 @@ class PerfModel:
         The fused solve path concatenates regressor rows from every
         (query, subQ, candidate) of a micro-batch into one call here.  Rows
         are zero-padded to a power-of-two bucket so the compile cache sees
-        O(log n_max) signatures across a serving session, and the padded
-        buffers are donated to XLA on accelerator backends.  Per-row
-        outputs equal :meth:`predict`'s on the same rows.
+        O(log n_max) signatures across a serving session.  Per-row outputs
+        equal :meth:`predict`'s on the same rows.
         """
         emb = np.ascontiguousarray(emb, np.float32)
         theta = np.ascontiguousarray(theta, np.float32)
         nond = np.ascontiguousarray(nond, np.float32)
         n = theta.shape[0]
+        if n == 0:
+            return np.zeros((0, self.cfg.n_targets), np.float32)
         cap = _head_max_bucket()
-        head = self._head if jax.default_backend() == "cpu" \
-            else self._head_donated
         outs = []
         for off in range(0, n, cap):
             e = emb[off:off + cap]
@@ -288,7 +297,7 @@ class PerfModel:
                 dp[:c] = d
                 e, t, d = ep, tp, dp
             self.head_buckets.add((b, theta.shape[1]))
-            z = head(self.params, e, t, d)
+            z = self._head(self.params, e, t, d)
             outs.append(np.asarray(z[:c]))
         return self.from_z(outs[0] if len(outs) == 1
                            else np.concatenate(outs, 0))

@@ -473,15 +473,15 @@ def _hmooc2_fixed_c(Fb: np.ndarray, Ib: np.ndarray, n_weights: int
 def _hmooc2_all_fused(Uc: np.ndarray, pool: np.ndarray, F_bank: np.ndarray,
                       idx_bank: np.ndarray, n_weights: int
                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Kernel-regime HMOOC2: the whole aggregation in one compiled solve.
+    """Kernel-regime HMOOC2: the whole aggregation in two device dispatches.
 
-    Composes the ``ws_reduce`` picks, the objective-sum gather, the
-    per-candidate dominance mask and the final global Pareto filter under a
-    single jit (``repro.kernels.fused_solve``) instead of bouncing
-    intermediate banks between host and device per candidate.  Returns the
-    already-globally-filtered (front, theta_c, theta_ps) in the same row
-    order the per-candidate numpy route produces (candidate-major, weight
-    ascending), with its same f32 score/compare semantics.
+    One ``ws_reduce`` pass picks for every candidate and one global Pareto
+    filter runs across all of them (``repro.kernels.fused_solve``), instead
+    of bouncing intermediate banks between host and device per candidate;
+    the objective sums and per-candidate masks stay float64 on the host.
+    Returns the already-globally-filtered (front, theta_c, theta_ps) in the
+    same row order the per-candidate numpy route produces (candidate-major,
+    weight ascending), with its same f32 score/compare semantics.
     """
     from ...kernels.fused_solve import fused_ws_front  # lazy: optional layer
     N, m, B, k = F_bank.shape

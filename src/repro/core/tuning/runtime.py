@@ -390,6 +390,9 @@ def _score_model_group(reqs: Sequence[ScoreRequest], members: List[int],
                        out: List[Optional[np.ndarray]]) -> None:
     model = reqs[members[0]].backend.model_for(decision)
     ns = [reqs[i].n for i in members]
+    # Cold embeddings of the whole group in one embed_many call.
+    model.embed_many([(reqs[i].backend.query, reqs[i].subq.sq_id)
+                      for i in members])
     thetas, embs, nonds = [], [], []
     for i, n in zip(members, ns):
         rq = reqs[i]

@@ -39,6 +39,7 @@ import numpy as np
 from ..archs.registry import ARCH_IDS, build_model, get_config
 from ..launch.hlo_analysis import (collective_bytes, hlo_flops_bytes,
                                    roofline_terms)
+from ..launch.mesh import auto_mesh
 from ..launch.shapes import (SHAPES, ShapeCell, cell_applicable,
                              serve_input_specs, train_input_specs)
 from ..train.optimizer import OptConfig, opt_init
@@ -64,7 +65,7 @@ def make_meshes(multi_pod: bool):
     else:
         shape = (1, n) if not multi_pod else (1, 1, n)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def _active_params(cfg, params_shape) -> float:

@@ -14,13 +14,25 @@ from typing import Optional, Tuple
 
 import jax
 
-__all__ = ["make_production_mesh", "make_host_mesh"]
+__all__ = ["make_production_mesh", "make_host_mesh", "auto_mesh"]
+
+
+def auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The model code places arrays through sharding constraints and lets the
+    partitioner propagate the rest; ``jax.make_mesh`` now defaults to
+    ``Explicit`` axes, under which those constraints and an unannotated
+    gather from a sharded embedding table are refused.
+    """
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape: Optional[Tuple[int, ...]] = None,
@@ -29,4 +41,4 @@ def make_host_mesh(shape: Optional[Tuple[int, ...]] = None,
     n = len(jax.devices())
     if shape is None:
         shape = (n, 1) if len(axes) == 2 else (n,)
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)

@@ -158,6 +158,29 @@ def test_fused_stage_eval_matches_per_request(smoke_perf_models):
         np.testing.assert_array_equal(g, r)
 
 
+def test_embedding_independent_of_dispatch_size(smoke_perf_models):
+    """A subQ embedded alone equals its row of a many-graph embed_many
+    dispatch (the served micro-batch prefetch vs the per-query solve)."""
+    from repro.core.models.perf_model import PerfModel
+    model = smoke_perf_models["subq"]
+
+    def twin():
+        return PerfModel(model.cfg, params=model.params,
+                         target_stats=model.target_stats)
+
+    qs = make_benchmark("tpch")
+    pairs = [(q, i) for q in qs[:6] for i in range(q.n_subqs)]
+    batched = twin()
+    batched.embed_many(pairs)
+    alone = twin()
+    for q, i in pairs[:12]:
+        np.testing.assert_array_equal(alone.embed(q, i),
+                                      batched.embed(q, i))
+    # One dispatch shape, however many graphs a call brings.
+    assert batched.embed_buckets == alone.embed_buckets
+    assert len(alone.embed_buckets) == 1
+
+
 def test_fused_stage_eval_oracle_fallback():
     """Oracle backend (model=None) falls back to per-request evaluation."""
     q = make_benchmark("tpch")[1]

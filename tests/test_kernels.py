@@ -155,3 +155,53 @@ def test_fused_ws_front_padding_invalid():
     assert not keep[2].any()          # invalid candidate filtered
     assert keep.any()                 # but the rest produce a front
     assert np.isfinite(P_all[keep]).all()
+
+
+def _f32_running_sum(x: np.ndarray) -> np.float32:
+    s = np.float32(x[0])
+    for t in x[1:]:
+        s = np.float32(s + np.float32(t))
+    return s
+
+
+def test_fused_route_keeps_front_when_f32_sums_tie():
+    """Two weight picks whose objective sums differ in float64 (and after a
+    float32 cast) but tie when added up in float32: the fused kernel route
+    must keep both, like the float64 numpy route."""
+    from repro.core.moo.hmooc import _hmooc2_all_fused, dag_aggregate
+    from repro.kernels.fused_solve import fused_ws_front, fused_ws_front_ref
+
+    m, one, ulp = 48, np.float32(1.0), np.float32(2.0 ** -23)
+    rng = np.random.default_rng(0)
+    while True:   # per-subQ latencies, exact in float32, b < a everywhere
+        a = one + rng.integers(0, 8, m).astype(np.float32) * ulp
+        b = a - rng.integers(1, 3, m).astype(np.float32) * ulp
+        sa, sb = a.astype(np.float64).sum(), b.astype(np.float64).sum()
+        if _f32_running_sum(a) == _f32_running_sum(b) and \
+                np.float32(sb) < np.float32(sa):
+            break
+    # Bank 0: (a, cost 1); bank 1: (b, cost 2).  Weight (0, 1) picks bank 0
+    # everywhere, weight (1, 0) bank 1: two mutually non-dominated points.
+    F_bank = np.empty((1, m, 2, 2))
+    F_bank[0, :, 0] = np.stack([a, np.ones(m)], -1)
+    F_bank[0, :, 1] = np.stack([b, np.full(m, 2.0)], -1)
+    idx_bank = np.tile(np.arange(2), (1, m, 1))
+    Uc = np.array([[0.5, 0.25]])
+    pool = np.array([[0.0], [1.0]])
+
+    front, tc, tps = dag_aggregate(Uc, pool, F_bank, idx_bank, "hmooc2",
+                                   n_ws_weights=2)
+    assert front.shape[0] == 2        # the numpy route keeps both picks
+    got = _hmooc2_all_fused(Uc, pool, F_bank, idx_bank, 2)
+    for x, y in zip(got, (front, tc, tps)):
+        np.testing.assert_array_equal(x, y)
+
+    W = np.array([[0.0, 1.0], [1.0, 0.0]])
+    Fn = ((F_bank - F_bank.min((1, 2), keepdims=True))
+          / np.ptp(F_bank, axis=(1, 2), keepdims=True)).astype(np.float32)
+    jj, P_all, keep = fused_ws_front(Fn, F_bank, W)
+    jr, Pr, kr = fused_ws_front_ref(Fn, F_bank, W)
+    assert keep.all()
+    np.testing.assert_array_equal(jj, jr)
+    np.testing.assert_array_equal(P_all, Pr)
+    np.testing.assert_array_equal(keep, kr)
