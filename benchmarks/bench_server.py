@@ -56,6 +56,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.moo.hmooc import HMOOCConfig
 from repro.queryengine.scenarios import scenario_matrix
 from repro.queryengine.workloads import (ArrivalModel, TenantSpec,
@@ -942,11 +943,12 @@ def run_model_solve(bench: str = "tpch", batch: int = 32,
     * **bit identity** — per-query results of the two paths compare equal
       on every batch.
     * **recompilation bound** — a varying-batch sweep (dedup off) on the
-      jit-path model, then ``compile_stats()``: compiled signatures must
-      not exceed the shape buckets actually seen.
+      jit-path model: the compiles counted under its dispatch span
+      (:mod:`repro.obs`) must not exceed the shape buckets
+      ``compile_stats()`` saw.
     * **tail latency** — a model-backed ``OptimizerServer`` stream at
       ``rate_qps``; reports p99 solve latency and the solve throughput
-      inside flush windows (``ServerStats.tune_windows``).
+      inside the solver's spans (``ServerStats.trace``).
     """
     cfg = cfg if cfg is not None else HMOOCConfig(seed=seed, **SERVING_CFG)
     base = model if model is not None else _train_bench_model(
@@ -966,8 +968,10 @@ def run_model_solve(bench: str = "tpch", batch: int = 32,
             times.append(time.perf_counter() - t0)
         return times, results
 
-    legacy_times, legacy_results = _run(m_legacy, False)
-    jit_times, jit_results = _run(m_jit, None)
+    with obs.record() as legacy_rec:
+        legacy_times, legacy_results = _run(m_legacy, False)
+    with obs.record() as jit_rec:
+        jit_times, jit_results = _run(m_jit, None)
     speedup = legacy_times[0] / jit_times[0]
     speedup_sustained = sum(legacy_times) / sum(jit_times)
 
@@ -982,14 +986,15 @@ def run_model_solve(bench: str = "tpch", batch: int = 32,
     # pow2 bucket, so signatures stay ≤ buckets however sizes vary.
     stream = list(serving_stream(bench, sum(sweep), seed=seed + 2))
     svc = TuningService(model=m_jit, cfg=cfg, dedupe=False)
-    for size in sweep:
-        chunk, stream = stream[:size], stream[size:]
-        svc.tune_batch(chunk, WEIGHTS)
+    with obs.record(jit_rec):
+        for size in sweep:
+            chunk, stream = stream[:size], stream[size:]
+            svc.tune_batch(chunk, WEIGHTS)
     cstats = m_jit.compile_stats()
-    lstats = m_legacy.compile_stats()
-    compile_bound_ok = (
-        cstats["head_compiles"] <= len(cstats["head_buckets"])
-        and cstats["embed_compiles"] <= len(cstats["embed_buckets"]))
+    dispatch_compiles = "compiles@repro.model.dispatch." + m_jit.cfg.kind
+    compile_bound_ok = (jit_rec.counter(dispatch_compiles)
+                        <= len(cstats["head_buckets"])
+                        + len(cstats["embed_buckets"]))
     from repro.kernels.fused_solve import SEEN_BUCKETS
 
     # Model-backed streaming at the target arrival rate.
@@ -1000,8 +1005,9 @@ def run_model_solve(bench: str = "tpch", batch: int = 32,
         bench, n_stream, seed=seed + 3,
         arrivals=ArrivalModel(kind="poisson", rate_qps=rate_qps)))
     rep = srv.latency_report(served)
-    tw = srv.last_run.tune_windows
-    solve_busy = sum(dt for dt, _ in tw)
+    tr = srv.last_run.trace
+    solve_busy = sum(tr.total_s(name) for name in tr.spans
+                     if name.startswith("repro.solve."))
 
     return {
         "bench": bench,
@@ -1013,15 +1019,14 @@ def run_model_solve(bench: str = "tpch", batch: int = 32,
         "jit_qps": batch / jit_times[0],
         "legacy_qps_sustained": batch * n_batches / sum(legacy_times),
         "jit_qps_sustained": batch * n_batches / sum(jit_times),
-        "legacy_head_compiles": lstats["head_compiles"],
+        "legacy_model_compiles": legacy_rec.counter(dispatch_compiles),
         "speedup_batched_vs_legacy": speedup,
         "speedup_sustained": speedup_sustained,
         "speedup_target_5x": speedup_sustained >= 5.0,
         "outputs_identical": outputs_identical,
         "sweep_batch_sizes": list(sweep),
-        "head_compiles": cstats["head_compiles"],
+        "model_compiles": jit_rec.counter(dispatch_compiles),
         "head_buckets": [list(b) for b in cstats["head_buckets"]],
-        "embed_compiles": cstats["embed_compiles"],
         "embed_buckets": cstats["embed_buckets"],
         "fused_buckets_seen": sorted(list(b) for b in SEEN_BUCKETS),
         "compile_bound_ok": compile_bound_ok,
@@ -1034,7 +1039,7 @@ def run_model_solve(bench: str = "tpch", batch: int = 32,
             "plan_latency_s": rep["plan_latency_s"],
             "solve_latency_s": rep["solve_latency_s"],
             "solve_qps_in_flushes":
-                (sum(b for _, b in tw) / solve_busy
+                (tr.counter("solve.solved") / solve_busy
                  if solve_busy else float("inf")),
             "p99_solve_under_budget":
                 rep["solve_latency_s"]["p99"] < budget_s,
@@ -1117,10 +1122,9 @@ def main():
             if not res["compile_bound_ok"]:
                 raise SystemExit(
                     f"recompilation bound violated: "
-                    f"{res['head_compiles']} head signatures for "
-                    f"{len(res['head_buckets'])} buckets, "
-                    f"{res['embed_compiles']} embed signatures for "
-                    f"{len(res['embed_buckets'])} buckets")
+                    f"{res['model_compiles']} model compiles for "
+                    f"{len(res['head_buckets'])} head and "
+                    f"{len(res['embed_buckets'])} embed buckets")
             if not res["stream"]["p99_solve_under_budget"]:
                 raise SystemExit(
                     f"model-backed p99 solve latency "
@@ -1214,12 +1218,12 @@ def main():
               f"batched vs legacy at batch {res['batch']} "
               f"({res['jit_qps']:.1f} vs {res['legacy_qps']:.1f} q/s, "
               f"sustained {res['speedup_sustained']:.2f}x, legacy compiled "
-              f"{res['legacy_head_compiles']} signatures vs "
-              f"{res['head_compiles']}) | "
-              f"identical: {res['outputs_identical']} | signatures "
-              f"head {res['head_compiles']}/{len(res['head_buckets'])} "
-              f"embed {res['embed_compiles']}/{len(res['embed_buckets'])} "
-              f"(bound ok: {res['compile_bound_ok']}) | stream @ "
+              f"{res['legacy_model_compiles']} signatures vs "
+              f"{res['model_compiles']}) | "
+              f"identical: {res['outputs_identical']} | compiles "
+              f"{res['model_compiles']} for "
+              f"{len(res['head_buckets']) + len(res['embed_buckets'])} "
+              f"buckets (bound ok: {res['compile_bound_ok']}) | stream @ "
               f"{res['stream']['rate_qps']:.0f} q/s solve p99 "
               f"{res['stream']['solve_latency_s']['p99'] * 1e3:.0f} ms")
         for p in save_bench("server_model_solve", res):

@@ -389,11 +389,16 @@ def serve_and_check(models: Dict[str, PerfModel], *, cfg: HMOOCConfig,
     streams["tpcds"] = _tpcds_requests(n_tpcds, seed, 0.0)
     ref_sub, ref_qs = _twin(msub), _twin(mqs)
     out: dict = {"cfg": dataclasses.asdict(cfg)}
+    compiles: Dict[str, int] = collections.Counter()
     with count_routes() as counts:
         served_all = []
         t0 = time.perf_counter()
         for name, reqs in streams.items():
             served = server.serve(reqs)
+            compiles.update({
+                k.split("@", 1)[1]: v
+                for k, v in server.last_run.trace.counters.items()
+                if k.startswith("compiles@")})
             statuses = collections.Counter(s.status for s in served)
             if statuses != {"served": len(reqs)}:
                 raise AssertionError(f"{name}: statuses {dict(statuses)}")
@@ -416,6 +421,7 @@ def serve_and_check(models: Dict[str, PerfModel], *, cfg: HMOOCConfig,
     out["routes"] = _route_summary(counts)
     out["compile_stats"] = {"subq": msub.compile_stats(),
                             "qs": mqs.compile_stats()}
+    out["compiles_by_span"] = dict(compiles)
     return out
 
 
@@ -468,6 +474,8 @@ def main() -> int:
         f"{srv['reference_s']:.3f} s (one smoke run, not a benchmark)")
     log(f"[routes] {json.dumps(srv['routes'])}")
     log(f"[compile_stats] {json.dumps(srv['compile_stats'])}")
+    log(f"[compiles while serving, by span] "
+        f"{json.dumps(srv['compiles_by_span'])}")
     log(f"[fused_solve.SEEN_BUCKETS] {sorted(fused_pkg.SEEN_BUCKETS)}")
     log(f"[phase seconds, one smoke run, not a benchmark] "
         f"{json.dumps(phases)}")

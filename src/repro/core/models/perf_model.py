@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
 from ...queryengine.plan import Query
 from .features import batch_graphs, featurize_plan, featurize_subq
 from .gtn import GTNConfig, gtn_apply, gtn_apply_batch, gtn_init
@@ -198,9 +199,10 @@ class PerfModel:
         chunk is padded with replicas of its first graph, sliced off
         afterwards), so one compiled signature serves every batch and an
         embedding does not depend on how many graphs share its dispatch.
-        Every chunk is dispatched before any result is read back, so the
-        host builds the next chunk while the device runs the last.
+        Every chunk is built before the first is dispatched, and every
+        chunk is dispatched before any result is read back.
         """
+        kind = self.cfg.kind
         todo = []
         seen = set()
         for query, sq_id in pairs:
@@ -208,28 +210,38 @@ class PerfModel:
             if key in self._emb_cache or key in seen:
                 continue
             seen.add(key)
-            if self.cfg.kind in ("subq", "qs"):
-                g = featurize_subq(query, sq_id, use_est=self.cfg.use_est,
-                                   n_pad=self.cfg.pad)
-            else:
-                g = featurize_plan(query, use_est=True, n_pad=self.cfg.pad)
-            todo.append((key, g))
+            todo.append((key, query, sq_id))
         if not todo:
             return
         b = max(1, EMBED_CHUNK_NODES // self.cfg.pad)
         self.embed_buckets.add(b)
-        chunks, outs = [], []
-        for off in range(0, len(todo), b):
-            chunk = todo[off:off + b]
-            graphs = [g for _, g in chunk] + [chunk[0][1]] * (b - len(chunk))
-            gb = batch_graphs(graphs)
-            chunks.append(chunk)
-            outs.append(self._embed_batch(self.params, gb.X, gb.pe, gb.bias,
-                                          gb.mask))
-        for chunk, out in zip(chunks, outs):
-            emb = np.asarray(out)
-            for j, (key, _) in enumerate(chunk):
-                self._emb_cache[key] = emb[j]
+        with obs.span("repro.model.featurize." + kind):
+            if kind in ("subq", "qs"):
+                graphs = [featurize_subq(query, sq_id,
+                                         use_est=self.cfg.use_est,
+                                         n_pad=self.cfg.pad)
+                          for _, query, sq_id in todo]
+            else:
+                graphs = [featurize_plan(query, use_est=True,
+                                         n_pad=self.cfg.pad)
+                          for _, query, _ in todo]
+            keys = [key for key, _, _ in todo]
+            chunks, batches = [], []
+            for off in range(0, len(todo), b):
+                chunk = graphs[off:off + b]
+                chunks.append(keys[off:off + b])
+                batches.append(batch_graphs(
+                    chunk + [chunk[0]] * (b - len(chunk))))
+        obs.count("model.graphs." + kind, len(todo))
+        obs.count("model.dispatches." + kind, len(batches))
+        with obs.span("repro.model.dispatch." + kind):
+            outs = [self._embed_batch(self.params, gb.X, gb.pe, gb.bias,
+                                      gb.mask) for gb in batches]
+        with obs.span("repro.model.readback." + kind):
+            for chunk, out in zip(chunks, outs):
+                emb = np.asarray(out)
+                for j, key in enumerate(chunk):
+                    self._emb_cache[key] = emb[j]
 
     # -- target transform ------------------------------------------------------
     def to_z(self, y: np.ndarray) -> np.ndarray:
@@ -277,42 +289,46 @@ class PerfModel:
         n = theta.shape[0]
         if n == 0:
             return np.zeros((0, self.cfg.n_targets), np.float32)
+        kind = self.cfg.kind
         cap = _head_max_bucket()
-        outs = []
-        for off in range(0, n, cap):
-            e = emb[off:off + cap]
-            t = theta[off:off + cap]
-            d = nond[off:off + cap]
-            c = t.shape[0]
-            # Calls larger than the cap reuse the cap signature for their
-            # tail too (waste < cap rows on a multi-cap call); only calls
-            # that fit in one chunk get a smaller bucket of the ladder.
-            b = cap if n > cap else pow2_bucket(c)
-            if b != c:
-                ep = np.zeros((b, e.shape[1]), np.float32)
-                ep[:c] = e
-                tp = np.zeros((b, t.shape[1]), np.float32)
-                tp[:c] = t
-                dp = np.zeros((b, d.shape[1]), np.float32)
-                dp[:c] = d
-                e, t, d = ep, tp, dp
-            self.head_buckets.add((b, theta.shape[1]))
-            z = self._head(self.params, e, t, d)
-            outs.append(np.asarray(z[:c]))
+        chunks = []
+        with obs.span("repro.model.pad." + kind):
+            for off in range(0, n, cap):
+                e = emb[off:off + cap]
+                t = theta[off:off + cap]
+                d = nond[off:off + cap]
+                c = t.shape[0]
+                # Calls larger than the cap reuse the cap signature for
+                # their tail too (waste < cap rows on a multi-cap call);
+                # only calls that fit in one chunk get a smaller bucket of
+                # the ladder.
+                b = cap if n > cap else pow2_bucket(c)
+                if b != c:
+                    ep = np.zeros((b, e.shape[1]), np.float32)
+                    ep[:c] = e
+                    tp = np.zeros((b, t.shape[1]), np.float32)
+                    tp[:c] = t
+                    dp = np.zeros((b, d.shape[1]), np.float32)
+                    dp[:c] = d
+                    e, t, d = ep, tp, dp
+                self.head_buckets.add((b, theta.shape[1]))
+                chunks.append((e, t, d, c))
+        obs.count("model.rows." + kind, n)
+        obs.count("model.dispatches." + kind, len(chunks))
+        with obs.span("repro.model.dispatch." + kind):
+            zs = [(self._head(self.params, e, t, d), c)
+                  for e, t, d, c in chunks]
+        with obs.span("repro.model.readback." + kind):
+            outs = [np.asarray(z[:c]) for z, c in zs]
         return self.from_z(outs[0] if len(outs) == 1
                            else np.concatenate(outs, 0))
 
     def compile_stats(self) -> dict:
-        """Signature accounting for the recompilation-bound assertions."""
-        def _cache_size(f):
-            try:
-                return int(f._cache_size())
-            except Exception:
-                return -1
+        """Shape buckets the padded batch paths have used: the bound on
+        the signatures each jitted function compiles.  The compiles
+        themselves are counted per span by :mod:`repro.obs`."""
         return {"head_buckets": sorted(self.head_buckets),
-                "embed_buckets": sorted(self.embed_buckets),
-                "head_compiles": _cache_size(self._head),
-                "embed_compiles": _cache_size(self._embed_batch)}
+                "embed_buckets": sorted(self.embed_buckets)}
 
     # -- persistence ----------------------------------------------------------
     def save(self, path: str) -> None:

@@ -65,6 +65,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.models.features import contention_gamma
 from ..core.models.perf_model import PerfModel
 from ..core.tuning.compile_time import CompileTimeResult
@@ -96,10 +97,6 @@ class RuntimeSessionStats:
         if self.requests_total == 0:
             return 0.0
         return 1.0 - self.requests_sent / self.requests_total
-
-    @property
-    def requests_per_sec(self) -> float:
-        return self.requests_sent / self.wall_time if self.wall_time else 0.0
 
 
 @dataclasses.dataclass
@@ -249,18 +246,22 @@ class RuntimeSession:
             return 0
         self.rounds_total += 1
         reqs, cands = [], []
-        for e in waiting:
-            sr, cand = e.backend.request_for(e.pending)
-            if e.gamma_raw is not None:
-                sr.gamma = self._live_gamma(e, sr.subq.sq_id)
-            reqs.append(sr)
-            cands.append(cand)
+        with obs.span("repro.runtime.candidates"):
+            for e in waiting:
+                sr, cand = e.backend.request_for(e.pending)
+                if e.gamma_raw is not None:
+                    sr.gamma = self._live_gamma(e, sr.subq.sq_id)
+                reqs.append(sr)
+                cands.append(cand)
         self.fused_total += len({fusion_key(sr) for sr in reqs}) + 1  # + pick
-        Fs = score_requests(reqs)
-        picks = weighted_pick_batch(
-            Fs, np.asarray([e.weights for e in waiting], np.float64))
-        for e, cand, j in zip(waiting, cands, picks):
-            self._step(e, cand[j])
+        with obs.span("repro.runtime.score"):
+            Fs = score_requests(reqs)
+        with obs.span("repro.runtime.pick"):
+            picks = weighted_pick_batch(
+                Fs, np.asarray([e.weights for e in waiting], np.float64))
+        with obs.span("repro.runtime.aqe"):
+            for e, cand, j in zip(waiting, cands, picks):
+                self._step(e, cand[j])
         return len(waiting)
 
     def _live_gamma(self, e: _Entry, sq_id: int) -> np.ndarray:
@@ -298,7 +299,8 @@ class RuntimeSession:
         (streaming) and realizing one big batch (offline) produce identical
         per-query results.
         """
-        return self._realize_batch(list(entries))
+        with obs.span("repro.runtime.realize"):
+            return self._realize_batch(list(entries))
 
     # -- closed-set convenience ---------------------------------------------
     def run_batch(

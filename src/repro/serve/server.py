@@ -78,6 +78,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.models.perf_model import PerfModel
 from ..core.moo.hmooc import HMOOCConfig
 from ..core.tuning.compile_time import CompileTimeResult
@@ -257,6 +258,12 @@ class ServedQuery:
     result: Optional[AQEResult] = None
     worker: Optional[int] = None       # fleet replica index that served it
                                        # (None outside a fleet)
+    flush_id: Optional[int] = None     # its micro-batch's index in the call
+    # Part of admitted_s − arrival_s during which the server was busy with
+    # a flush or round for other requests; the rest of the wait is the
+    # batcher holding the request on an idle server.
+    busy_wait_s: float = math.nan
+    trace: Optional[obs.ServeTrace] = None  # the serve() call's record
 
     @property
     def solve_latency_s(self) -> float:
@@ -289,14 +296,11 @@ class ServerStats:
     # EWMAs — the reserve regression test replays these.
     flush_windows: List[Tuple[float, int]] = dataclasses.field(
         default_factory=list)
-    # Per-flush (tune_batch wall time, batch size): the compile-time solve
-    # slice of each flush window, excluding AQE admission — what the
-    # jitted-solve benchmarks report p99 solve latency from.
-    tune_windows: List[Tuple[float, int]] = dataclasses.field(
-        default_factory=list)
     # Per-flush batch cap in effect at compose time (capacity events +
     # elastic scaling visible per flush; constant without either).
     flush_caps: List[int] = dataclasses.field(default_factory=list)
+    # Spans and counters of the run (see repro.obs).
+    trace: Optional[obs.ServeTrace] = None
 
     @property
     def qps(self) -> float:
@@ -387,7 +391,21 @@ class OptimizerServer:
         exactly those weights (scenario streams stamp mid-stream
         preference shifts per request at build time); otherwise the
         tenant's registered weights apply.
+
+        The call's spans and counters (:mod:`repro.obs`) are recorded in
+        one :class:`~repro.obs.ServeTrace`, kept as ``last_run.trace`` and
+        on every returned request.
         """
+        with obs.record() as rec:
+            out = self._serve(requests, capacity_events)
+        self.last_run.trace = rec
+        for s in out:
+            s.trace = rec
+        return out
+
+    def _serve(self, requests: Sequence[StreamRequest],
+               capacity_events: Sequence[Tuple[float, int]]
+               ) -> List[ServedQuery]:
         wall0 = time.perf_counter()
         cfgv = self.config
         sched = self.scheduler
@@ -419,8 +437,13 @@ class OptimizerServer:
         n_degraded = 0
         n_rate_limited = 0
         flush_windows: List[Tuple[float, int]] = []
-        tune_windows: List[Tuple[float, int]] = []
         flush_caps: List[int] = []
+        # The clock advances only by work windows or by idle jumps, and an
+        # idle jump always ends at an arrival, a deadline or a capacity
+        # event; so the idle time that passed while a request waited is the
+        # growth of this total between its arrival and its admission.
+        idle_s = 0.0
+        idle_at_arrival: Dict[int, float] = {}
         flushes_since_round = 0
         rounds0 = self.session.rounds_total
         slots0 = {st.name: st.slots_granted for st in sched.states()}
@@ -445,6 +468,7 @@ class OptimizerServer:
             while pos < len(incoming) and incoming[pos].arrival_s <= now:
                 s = incoming[pos]
                 if sched.admit_arrival(s.tenant, s, s.arrival_s):
+                    idle_at_arrival[s.rid] = idle_s
                     pos += 1
                     continue
                 # Door rejection: the token bucket (clocked by arrival
@@ -484,90 +508,100 @@ class OptimizerServer:
         while pos < len(incoming) or sched.total_waiting() or in_flight:
             apply_capacity(t)
             if flush_due(t):
-                cap = cur_cap()
-                # Overload triage first: strict-SLO requests whose budget is
-                # already unmeetable are rejected here — first-class
-                # outcomes, never solved, never poisoning latency stats.
-                for _, s in sched.shed_unmeetable(t, cap):
-                    s.status = "shed"
-                    s.finished_s = t
-                    n_shed += 1
-                lead = (self.elastic.degrade_lead_s(
-                            cfgv.solve_budget_s, sched.default_reserve_q_s,
-                            base_cap)
-                        if self.elastic else 0.0)
-                admits = sched.compose(t, cap, lead)
-                if not admits:
-                    continue           # everything waiting was shed
-                batch = [a.item for a in admits]
-                n_batches += 1
-                flushes_since_round += 1
-                flush_caps.append(cap)
-                if self.elastic:
-                    # Observed queue delay of this flush (mean wait at
-                    # compose time) feeds the forecast for the next one.
-                    self.elastic.note_flush(
-                        sum(t - s.arrival_s for s in batch) / len(batch))
-                for a, s in zip(admits, batch):
-                    s.admitted_s = t
-                    if a.degrade:
-                        s.status = "degraded"
-                        n_degraded += 1
-                batch_w = [tuple(s.request.weights)
-                           if s.request.weights is not None
-                           else self.tenant_weights(s.tenant)
-                           for s in batch]
-                t0 = time.perf_counter()
-                cts = self.tuning.tune_batch(
-                    [s.request.query for s in batch], batch_w,
-                    tenants=[s.tenant for s in batch],
-                    degraded=[a.degrade for a in admits])
-                tune_windows.append((self.tuning.last_batch.wall_time,
-                                     len(batch)))
-                joined_running = self.session.n_active > 0
-                for s, ct, w in zip(batch, cts, batch_w):
-                    s.ct = ct
-                    s.joined_running = joined_running
-                    if joined_running:
-                        n_joined_running += 1
-                    self.session.admit(
-                        s.request.query, ct, tag=s.rid, weights=w,
-                        pool_scope=(s.tenant if cfgv.isolate_tenant_pools
-                                    else None))
-                    in_flight[s.rid] = s
-                # One window feeds both the clock charge and the reserve
-                # EWMA: the whole flush — the batched solve plus each
-                # query's initial AQE planning step inside admit().
-                # (Feeding note_solve only the tune_batch slice made the
-                # reserve undershoot the true per-query admission cost.)
-                # Under a ServiceTimeModel the charged window is the
-                # model's, so the admission timeline is deterministic.
-                # Cheap members (cache hits + degraded paths, per the
-                # tuning service's own accounting of the flush we just
-                # ran) are priced at cheap_s instead of the solve curve.
-                n_cheap = len(batch) - self.tuning.last_batch.n_solved
-                window = (cfgv.clock.flush_s(len(batch), n_cheap)
-                          if cfgv.clock is not None
-                          else time.perf_counter() - t0)
-                sched.note_solve(window, len(batch),
-                                 (s.tenant for s in batch))
-                flush_windows.append((window, len(batch)))
-                t += window
-                for s in batch:
-                    s.compiled_s = t
-                admit_arrived(t)
+                with obs.span("repro.serve.flush"):
+                    cap = cur_cap()
+                    with obs.span("repro.admission.compose"):
+                        # Overload triage first: strict-SLO requests whose
+                        # budget is already unmeetable are rejected here —
+                        # first-class outcomes, never solved, never
+                        # poisoning latency stats.
+                        for _, s in sched.shed_unmeetable(t, cap):
+                            s.status = "shed"
+                            s.finished_s = t
+                            n_shed += 1
+                        lead = (self.elastic.degrade_lead_s(
+                                    cfgv.solve_budget_s,
+                                    sched.default_reserve_q_s, base_cap)
+                                if self.elastic else 0.0)
+                        admits = sched.compose(t, cap, lead)
+                    if not admits:
+                        continue           # everything waiting was shed
+                    batch = [a.item for a in admits]
+                    flush_id = n_batches
+                    n_batches += 1
+                    flushes_since_round += 1
+                    flush_caps.append(cap)
+                    if self.elastic:
+                        # Observed queue delay of this flush (mean wait at
+                        # compose time) feeds the forecast for the next one.
+                        self.elastic.note_flush(
+                            sum(t - s.arrival_s for s in batch) / len(batch))
+                    for a, s in zip(admits, batch):
+                        s.admitted_s = t
+                        s.flush_id = flush_id
+                        wait = t - s.arrival_s
+                        idle = idle_s - idle_at_arrival.pop(s.rid)
+                        s.busy_wait_s = min(max(wait - idle, 0.0), wait)
+                        if a.degrade:
+                            s.status = "degraded"
+                            n_degraded += 1
+                    batch_w = [tuple(s.request.weights)
+                               if s.request.weights is not None
+                               else self.tenant_weights(s.tenant)
+                               for s in batch]
+                    t0 = time.perf_counter()
+                    cts = self.tuning.tune_batch(
+                        [s.request.query for s in batch], batch_w,
+                        tenants=[s.tenant for s in batch],
+                        degraded=[a.degrade for a in admits])
+                    joined_running = self.session.n_active > 0
+                    with obs.span("repro.runtime.admit"):
+                        for s, ct, w in zip(batch, cts, batch_w):
+                            s.ct = ct
+                            s.joined_running = joined_running
+                            if joined_running:
+                                n_joined_running += 1
+                            self.session.admit(
+                                s.request.query, ct, tag=s.rid, weights=w,
+                                pool_scope=(s.tenant
+                                            if cfgv.isolate_tenant_pools
+                                            else None))
+                            in_flight[s.rid] = s
+                    # One window feeds both the clock charge and the reserve
+                    # EWMA: the whole flush — the batched solve plus each
+                    # query's initial AQE planning step inside admit().
+                    # (Feeding note_solve only the tune_batch slice made the
+                    # reserve undershoot the true per-query admission cost.)
+                    # Under a ServiceTimeModel the charged window is the
+                    # model's, so the admission timeline is deterministic.
+                    # Cheap members (cache hits + degraded paths, per the
+                    # tuning service's own accounting of the flush we just
+                    # ran) are priced at cheap_s instead of the solve curve.
+                    n_cheap = len(batch) - self.tuning.last_batch.n_solved
+                    window = (cfgv.clock.flush_s(len(batch), n_cheap)
+                              if cfgv.clock is not None
+                              else time.perf_counter() - t0)
+                    sched.note_solve(window, len(batch),
+                                     (s.tenant for s in batch))
+                    flush_windows.append((window, len(batch)))
+                    t += window
+                    for s in batch:
+                        s.compiled_s = t
+                    admit_arrived(t)
                 continue
             if self.session.has_pending() or self.session.n_active:
                 flushes_since_round = 0
-                t0 = time.perf_counter()
-                self.session.step_round()
-                done = self.session.retire_ready()
-                results = self.session.realize(done) if done else []
-                t += (cfgv.clock.round_cost_s() if cfgv.clock is not None
-                      else time.perf_counter() - t0)
-                if done:
-                    finish(done, results, t)
-                admit_arrived(t)
+                with obs.span("repro.serve.round"):
+                    t0 = time.perf_counter()
+                    self.session.step_round()
+                    done = self.session.retire_ready()
+                    results = self.session.realize(done) if done else []
+                    t += (cfgv.clock.round_cost_s()
+                          if cfgv.clock is not None
+                          else time.perf_counter() - t0)
+                    if done:
+                        finish(done, results, t)
+                    admit_arrived(t)
                 continue
             # Idle: jump the simulated clock to the next event (arrival,
             # flush deadline, or capacity change — a cap drop can make the
@@ -579,7 +613,9 @@ class OptimizerServer:
                       else math.inf)
             if not math.isfinite(nxt):
                 break
-            t = max(t, nxt)
+            if nxt > t:
+                idle_s += nxt - t
+                t = nxt
             admit_arrived(t)
             apply_capacity(t)
 
@@ -607,7 +643,6 @@ class OptimizerServer:
                           for st in sched.states()
                           if st.slots_granted - slots0.get(st.name, 0)},
             flush_windows=flush_windows,
-            tune_windows=tune_windows,
             flush_caps=flush_caps)
         return out
 

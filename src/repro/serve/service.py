@@ -25,6 +25,7 @@ from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .. import obs
 from ..core.models.perf_model import PerfModel
 from ..core.moo.hmooc import HMOOCConfig, HmoocPlan
 from ..core.tuning.compile_time import (CompileTimeResult,
@@ -284,6 +285,7 @@ class TuningService:
                 # repro: allow[CK002] full solves store under the exact key on purpose: degraded results are minted in _tune_cheap under degrade-marked keys, and an exact hit serving a later degraded request is the intended upgrade path
                 self._results.put(key, results[qi])
         flush_run()
+        obs.count("solve.solved", n_solved)
         dt = time.perf_counter() - t0
         self.last_batch = BatchStats(
             n_queries=len(queries), n_solved=n_solved,
@@ -323,94 +325,109 @@ class TuningService:
         Returns the number of actual solves (post-dedup).
         """
         model = self._model
-        # -- response planning: dedup within and across batches ------------
-        keys: dict = {}
-        pending: dict = {}            # key -> first qi solving it this run
-        deferred_gets: List[Tuple[int, tuple]] = []
-        solved: List[int] = []
-        for qi in idxs:
-            key = self._response_key(
-                queries[qi], per_q_weights[qi],
-                tenants[qi] if tenants is not None else None)
-            keys[qi] = key
-            if self._results is not None:
-                if key in pending:
-                    # An identical request is already solving in this run;
-                    # resolve the get after its put so the dedup registers
-                    # as a response-cache hit, like the sequential order.
-                    deferred_gets.append((qi, key))
-                    continue
-                hit = self._results.get(key)
-                if hit is not None:
-                    results[qi] = hit
-                    continue
-                pending[key] = qi
-            solved.append(qi)
-        if solved:
-            # -- embedding prefetch: one GTN dispatch for the whole run ----
-            pairs = []
-            for qi in solved:
-                pairs.extend((queries[qi], i)
-                             for i in range(queries[qi].n_subqs))
-            model.embed_many(pairs)
-            objs = {qi: StageObjectives(queries[qi], model=model,
-                                        cost=self.cost) for qi in solved}
-            # -- effective-set planning ------------------------------------
-            t0s: dict = {}
-            plans: dict = {}
-            deferred_lookup: set = set()
-            pending_eset: dict = {}   # template key -> (owner qi, owner fp)
-            waiting: List[Tuple[int, int]] = []   # (qi, owner qi)
-            for qi in solved:
-                q, obj = queries[qi], objs[qi]
-                t0s[qi] = time.perf_counter()
-                tk = template_key(q, self.cfg, model, self.cost)
-                fp = query_fingerprint(q)
-                if tk in pending_eset:
-                    # The template's banks are being (re)built by an
-                    # earlier query of this run; the cache lookup is
-                    # deferred past the owner's store so stats match the
-                    # sequential transcript.
-                    owner_qi, owner_fp = pending_eset[tk]
-                    deferred_lookup.add(qi)
-                    if (fp == owner_fp
-                            or self.cache.reuse_banks_across_variants):
-                        waiting.append((qi, owner_qi))
+        with obs.span("repro.solve.lookup"):
+            # -- response planning: dedup within and across batches --------
+            keys: dict = {}
+            pending: dict = {}            # key -> first qi solving it this run
+            deferred_gets: List[Tuple[int, tuple]] = []
+            solved: List[int] = []
+            for qi in idxs:
+                key = self._response_key(
+                    queries[qi], per_q_weights[qi],
+                    tenants[qi] if tenants is not None else None)
+                keys[qi] = key
+                if self._results is not None:
+                    if key in pending:
+                        # An identical request is already solving in this run;
+                        # resolve the get after its put so the dedup registers
+                        # as a response-cache hit, like the sequential order.
+                        deferred_gets.append((qi, key))
                         continue
-                    # Different variant, no cross-variant reuse: fresh
-                    # banks over the owner's (query-independent)
-                    # candidates; this query's store supersedes the
-                    # owner's, so it becomes the template's new owner.
+                    hit = self._results.get(key)
+                    if hit is not None:
+                        results[qi] = hit
+                        continue
+                    pending[key] = qi
+                solved.append(qi)
+            if solved:
+                # -- embedding prefetch: one GTN dispatch for the whole run
+                pairs = []
+                for qi in solved:
+                    pairs.extend((queries[qi], i)
+                                 for i in range(queries[qi].n_subqs))
+                model.embed_many(pairs)
+                objs = {qi: StageObjectives(queries[qi], model=model,
+                                            cost=self.cost) for qi in solved}
+                # -- effective-set planning --------------------------------
+                t0s: dict = {}
+                plans: dict = {}
+                deferred_lookup: set = set()
+                pending_eset: dict = {}  # template key -> (owner qi, owner fp)
+                waiting: List[Tuple[int, int]] = []   # (qi, owner qi)
+                for qi in solved:
+                    q, obj = queries[qi], objs[qi]
+                    t0s[qi] = time.perf_counter()
+                    tk = template_key(q, self.cfg, model, self.cost)
+                    fp = query_fingerprint(q)
+                    if tk in pending_eset:
+                        # The template's banks are being (re)built by an
+                        # earlier query of this run; the cache lookup is
+                        # deferred past the owner's store so stats match the
+                        # sequential transcript.
+                        owner_qi, owner_fp = pending_eset[tk]
+                        deferred_lookup.add(qi)
+                        if (fp == owner_fp
+                                or self.cache.reuse_banks_across_variants):
+                            waiting.append((qi, owner_qi))
+                            continue
+                        # Different variant, no cross-variant reuse: fresh
+                        # banks over the owner's (query-independent)
+                        # candidates; this query's store supersedes the
+                        # owner's, so it becomes the template's new owner.
+                        plans[qi] = HmoocPlan(
+                            q.n_subqs, obj.d_c, obj.d_ps, self.cfg,
+                            snap_c=obj.snap_c, snap_ps=obj.snap_ps,
+                            effective_set=(
+                                plans[owner_qi].eset.without_banks()))
+                        pending_eset[tk] = (qi, fp)
+                        continue
+                    eset = self.cache.lookup(q, self.cfg, model, self.cost)
                     plans[qi] = HmoocPlan(
                         q.n_subqs, obj.d_c, obj.d_ps, self.cfg,
                         snap_c=obj.snap_c, snap_ps=obj.snap_ps,
-                        effective_set=plans[owner_qi].eset.without_banks())
-                    pending_eset[tk] = (qi, fp)
-                    continue
-                eset = self.cache.lookup(q, self.cfg, model, self.cost)
-                plans[qi] = HmoocPlan(
-                    q.n_subqs, obj.d_c, obj.d_ps, self.cfg,
-                    snap_c=obj.snap_c, snap_ps=obj.snap_ps,
-                    effective_set=eset)
-                if not plans[qi].reused_banks:
-                    pending_eset[tk] = (qi, fp)
+                        effective_set=eset)
+                    if not plans[qi].reused_banks:
+                        pending_eset[tk] = (qi, fp)
+        if solved:
             # -- lockstep rounds: one fused model call per solver phase ----
             while True:
                 active = [qi for qi in solved
                           if qi in plans and not plans[qi].done]
                 if not active and not waiting:
                     break
-                items, spans = [], []
-                for qi in active:
-                    reqs = plans[qi].requests()
-                    items.extend((objs[qi], i, Tc, Tps)
-                                 for i, Tc, Tps in reqs)
-                    spans.append((qi, len(reqs)))
-                evals = fused_stage_eval(items)
+                with obs.span("repro.solve.rows"):
+                    items, spans = [], []
+                    for qi in active:
+                        reqs = plans[qi].requests()
+                        items.extend((objs[qi], i, Tc, Tps)
+                                     for i, Tc, Tps in reqs)
+                        spans.append((qi, len(reqs)))
+                    evals = fused_stage_eval(items)
+                # Feed by phase: Algorithm-1 bank builds, then the assign
+                # phase's HMOOC aggregation (each plan's feed reads only its
+                # own state, so the order across plans is free).
+                by_phase: dict = {False: [], True: []}
                 off = 0
                 for qi, n in spans:
-                    plans[qi].feed(evals[off:off + n])
+                    by_phase[plans[qi].banks_ready].append(
+                        (plans[qi], evals[off:off + n]))
                     off += n
+                for name, ready in (("repro.solve.hmooc.banks", False),
+                                    ("repro.solve.hmooc.assign", True)):
+                    if by_phase[ready]:
+                        with obs.span(name):
+                            for plan, ev in by_phase[ready]:
+                                plan.feed(ev)
                 still = []
                 for qi, owner_qi in waiting:
                     if plans[owner_qi].banks_ready:
@@ -424,21 +441,24 @@ class TuningService:
                         still.append((qi, owner_qi))
                 waiting = still
             # -- finalize in request order ---------------------------------
-            for qi in solved:
-                q, w = queries[qi], per_q_weights[qi]
-                if qi in deferred_lookup:
-                    # Stats-only replay of the lookup the sequential path
-                    # would have issued here (after the owner's store).
-                    self.cache.lookup(q, self.cfg, model, self.cost)
-                plan = plans[qi]
-                res = plan.result
-                if not plan.reused_banks and res.effective_set is not None:
-                    self.cache.store(q, self.cfg, res.effective_set, model,
-                                     self.cost)
-                ct = finish_result(q, objs[qi], res, w, t0s[qi])
-                results[qi] = ct
-                if self._results is not None:
-                    self._results.put(keys[qi], ct)
+            with obs.span("repro.solve.finish"):
+                for qi in solved:
+                    q, w = queries[qi], per_q_weights[qi]
+                    if qi in deferred_lookup:
+                        # Stats-only replay of the lookup the sequential
+                        # path would have issued here (after the owner's
+                        # store).
+                        self.cache.lookup(q, self.cfg, model, self.cost)
+                    plan = plans[qi]
+                    res = plan.result
+                    if not plan.reused_banks and \
+                            res.effective_set is not None:
+                        self.cache.store(q, self.cfg, res.effective_set,
+                                         model, self.cost)
+                    ct = finish_result(q, objs[qi], res, w, t0s[qi])
+                    results[qi] = ct
+                    if self._results is not None:
+                        self._results.put(keys[qi], ct)
         for qi, key in deferred_gets:
             results[qi] = self._results.get(key)
         return len(solved)

@@ -86,6 +86,8 @@ def test_serve_phase_matches_sequential_reference(smoke, models):
     assert routes["pareto"]["numpy"] > 0  # CPU thresholds keep numpy
     assert routes["pareto"]["kernel"] == 0
     assert out["compile_stats"]["subq"]["head_buckets"]
+    # The serve() record counts every compile under a program span.
+    assert all(k.startswith("repro.") for k in out["compiles_by_span"])
 
 
 def test_count_routes_restores_entry_points(smoke):
