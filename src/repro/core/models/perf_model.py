@@ -319,7 +319,9 @@ class PerfModel:
             zs = [(self._head(self.params, e, t, d), c)
                   for e, t, d, c in chunks]
         with obs.span("repro.model.readback." + kind):
-            outs = [np.asarray(z[:c]) for z, c in zs]
+            # Read back the whole bucket and slice on the host: a slice of
+            # the device array would compile for every new row count.
+            outs = [np.asarray(z)[:c] for z, c in zs]
         return self.from_z(outs[0] if len(outs) == 1
                            else np.concatenate(outs, 0))
 

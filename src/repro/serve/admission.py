@@ -7,9 +7,12 @@ per-request promise the server must keep for every tenant at once.
 :class:`~repro.serve.server.OptimizerServer`:
 
 * **Per-tenant queues + deadlines.**  Each tenant's requests wait in their
-  own FIFO; the tenant's flush deadline is its oldest request's
+  own FIFO; the tenant's deadline is its oldest request's
   ``arrival + budget − reserve`` where the reserve is a per-*query* EWMA of
-  recent solve times scaled by the expected batch size.  (Per-query
+  recent solve times scaled by the expected batch size: the latest flush
+  start that still meets the budget.  The server never holds a request
+  for its deadline (it flushes whenever it is idle); the deadline orders
+  composition and decides overload triage.  (Per-query
   normalization is the PR-4 bugfix: the old whole-batch EWMA let one large
   batch inflate the reserve applied to subsequent small batches.)
 * **Weighted-fair composition.**  A micro-batch is composed by
@@ -251,9 +254,8 @@ class TenantState:
 class TenantScheduler:
     """Deficit-round-robin admission over per-tenant queues.
 
-    Drives no clock of its own: the server asks ``next_deadline`` when
-    idle, tests ``flush_due``-style conditions itself, and calls
-    ``shed_unmeetable`` + ``compose`` to draw one micro-batch.  Unknown
+    Drives no clock of its own: the server decides when to flush, and
+    calls ``shed_unmeetable`` + ``compose`` to draw one micro-batch.  Unknown
     tenant names are auto-registered with default policy, so anonymous
     single-stream traffic needs no configuration.
     """
@@ -335,16 +337,6 @@ class TenantScheduler:
         ``picked=0`` and sees the genuinely shrunken pool.
         """
         return min(max(picked + self.total_waiting(), 1), cap)
-
-    def next_deadline(self, cap: int) -> float:
-        """Earliest flush deadline over all waiting tenants (inf if idle)."""
-        n = self._expected_n(cap)
-        return min((self._deadline(st, n)
-                    for st in self._states.values() if st.queue),
-                   default=math.inf)
-
-    def deadline_due(self, now: float, cap: int) -> bool:
-        return now >= self.next_deadline(cap)
 
     def unmeetable(self, st: TenantState, now: float, cap: int,
                    picked: int = 0) -> bool:

@@ -6,8 +6,8 @@ fully-formed batches.  :class:`OptimizerServer` closes the gap: it accepts
 queries as they arrive (a simulated-clock event queue fed by
 :func:`~repro.queryengine.workloads.serving_stream` or
 :func:`~repro.queryengine.workloads.multi_tenant_stream`), accumulates
-them into deadline-aware micro-batches, routes each micro-batch through
-the batched compile-time solve (:meth:`TuningService.tune_batch`) and then
+them into micro-batches, routes each micro-batch through the batched
+compile-time solve (:meth:`TuningService.tune_batch`) and then
 drives the resulting AQE generators through one long-lived, shared
 :class:`RuntimeSession` — admitting late arrivals into the *running*
 session between fusion rounds instead of holding them for the next batch.
@@ -25,17 +25,26 @@ candidate-pool cache is tenant-scoped too.  Fairness shapes *latency*
 only: per-query outputs equal the offline pipeline solved under that
 tenant's weights, so tenants can never perturb each other's plans.
 
-Admission policy (deadline-aware micro-batching):
+Admission policy (work-conserving micro-batching):
 
-* a micro-batch flushes when ``max_batch`` requests are waiting, or
-* when the simulated clock reaches some tenant's flush deadline
-  ``oldest arrival + tenant budget − reserve``, where the reserve is a
-  per-query EWMA of recent solve times scaled by the expected batch size
-  (seeded by ``solve_reserve_s``) — i.e. the latest moment solving can
-  start and still make that tenant's budget.
+* on an idle server (no runtime session active) a micro-batch flushes as
+  soon as anything waits: everything waiting, up to ``max_batch``,
+  composed by the scheduler's tier and deficit-round-robin order;
+* while a session is active, waiting requests join it between fusion
+  rounds, at most one flush per round.
+
+Nothing is held back for company: under load, requests pile up during
+flushes and rounds, so batches fill by themselves.  Which rule engaged is
+counted per flush as ``admission.flush.idle`` (idle server, fewer than
+``max_batch`` waiting), ``admission.flush.full`` (idle server, a full
+batch waiting) or ``admission.flush.session`` (joining a live session).
+Each tenant's deadline ``oldest arrival + tenant budget − reserve``, where
+the reserve is a per-query EWMA of recent solve times scaled by the
+expected batch size (seeded by ``solve_reserve_s``), orders composition
+and decides overload triage.
 
 Overload handling (PR 5): when a waiting request's budget has become
-*unmeetable* (its flush deadline has passed — even solving immediately
+*unmeetable* (its deadline has passed — even solving immediately
 would blow the budget), the tenant's SLO class decides: ``strict``
 requests are **shed** (``status="shed"``: rejected as first-class
 outcomes, never solved, excluded from latency percentiles), ``degrade``
@@ -213,7 +222,7 @@ class ServiceTimeModel:
 @dataclasses.dataclass(frozen=True)
 class ServerConfig:
     """Admission/scheduling policy of the streaming server."""
-    max_batch: int = 8                 # flush when this many requests wait
+    max_batch: int = 8                 # most requests in one flush
     solve_budget_s: float = 1.0        # the paper's per-query cloud budget
     solve_reserve_s: float = 0.25      # initial per-QUERY solve reserve (EWMA
                                        # seed; deadlines scale it by the
@@ -260,8 +269,9 @@ class ServedQuery:
                                        # (None outside a fleet)
     flush_id: Optional[int] = None     # its micro-batch's index in the call
     # Part of admitted_s − arrival_s during which the server was busy with
-    # a flush or round for other requests; the rest of the wait is the
-    # batcher holding the request on an idle server.
+    # a flush or round for other requests; the rest of the wait is time
+    # the server sat idle while the request waited (none: an idle server
+    # flushes at once).
     busy_wait_s: float = math.nan
     trace: Optional[obs.ServeTrace] = None  # the serve() call's record
 
@@ -439,9 +449,9 @@ class OptimizerServer:
         flush_windows: List[Tuple[float, int]] = []
         flush_caps: List[int] = []
         # The clock advances only by work windows or by idle jumps, and an
-        # idle jump always ends at an arrival, a deadline or a capacity
-        # event; so the idle time that passed while a request waited is the
-        # growth of this total between its arrival and its admission.
+        # idle jump always ends at an arrival; so the idle time that passed
+        # while a request waited is the growth of this total between its
+        # arrival and its admission.
         idle_s = 0.0
         idle_at_arrival: Dict[int, float] = {}
         flushes_since_round = 0
@@ -478,23 +488,24 @@ class OptimizerServer:
                 n_rate_limited += 1
                 pos += 1
 
-        def flush_due(now: float) -> bool:
-            if not sched.total_waiting():
-                return False
+        def flush_reason() -> Optional[str]:
+            """Why a micro-batch flushes now (the ``admission.flush.*``
+            counter it engages), or None to run a round or wait."""
+            n_waiting = sched.total_waiting()
+            if not n_waiting:
+                return None
             if self.session.n_active:
                 # A session is live: join it eagerly between fusion rounds
                 # (the optimizer is busy either way), unless running
                 # batch-only.  At most one flush per round, so sustained
                 # arrivals can never starve in-flight queries of the rounds
                 # they need to finish.
-                return cfgv.admit_mid_session and flushes_since_round < 1
-            if sched.total_waiting() >= cur_cap():
-                return True
-            if pos >= len(incoming):
-                # End of stream: nothing else will arrive, waiting longer
-                # only adds latency.
-                return True
-            return sched.deadline_due(now, cur_cap())
+                return ("session" if cfgv.admit_mid_session
+                        and flushes_since_round < 1 else None)
+            # Idle server: flush at once.  Holding a request for company
+            # only adds its wait to its latency; under load requests pile
+            # up during flushes and rounds, so batches fill by themselves.
+            return "full" if n_waiting >= cur_cap() else "idle"
 
         def finish(cohort, results, now: float) -> None:
             for e, res in zip(cohort, results):
@@ -507,7 +518,8 @@ class OptimizerServer:
         apply_capacity(t)
         while pos < len(incoming) or sched.total_waiting() or in_flight:
             apply_capacity(t)
-            if flush_due(t):
+            reason = flush_reason()
+            if reason is not None:
                 with obs.span("repro.serve.flush"):
                     cap = cur_cap()
                     with obs.span("repro.admission.compose"):
@@ -526,6 +538,7 @@ class OptimizerServer:
                         admits = sched.compose(t, cap, lead)
                     if not admits:
                         continue           # everything waiting was shed
+                    obs.count("admission.flush." + reason)
                     batch = [a.item for a in admits]
                     flush_id = n_batches
                     n_batches += 1
@@ -603,21 +616,15 @@ class OptimizerServer:
                         finish(done, results, t)
                     admit_arrived(t)
                 continue
-            # Idle: jump the simulated clock to the next event (arrival,
-            # flush deadline, or capacity change — a cap drop can make the
-            # waiting pool flush-ready with no new arrival).
-            nxt = min(incoming[pos].arrival_s if pos < len(incoming)
-                      else math.inf,
-                      sched.next_deadline(cur_cap()),
-                      cap_events[ev_pos][0] if ev_pos < len(cap_events)
-                      else math.inf)
-            if not math.isfinite(nxt):
+            # Idle, and nothing waits (an idle server flushes whatever
+            # does): jump the simulated clock to the next arrival.
+            if pos >= len(incoming):
                 break
+            nxt = incoming[pos].arrival_s
             if nxt > t:
                 idle_s += nxt - t
                 t = nxt
             admit_arrived(t)
-            apply_capacity(t)
 
         out = [served[r.rid] for r in requests]
         # Makespan spans *served* work only: a shed/rate-limited request's

@@ -358,15 +358,17 @@ def test_reserve_normalized_per_query():
     # The buggy whole-batch EWMA would have been 0.7*0.25 + 0.3*8.0 = 2.575,
     # pushing a single waiting query's deadline before its own arrival.
     sched.enqueue("a", "x", arrival_s=10.0)
-    dl = sched.next_deadline(cap=8)
-    assert dl == pytest.approx(10.0 + 1.0 - st_.reserve_q_s)
+    dl = 10.0 + 1.0 - st_.reserve_q_s
     assert dl > 10.0                            # still after arrival
+    assert not sched.unmeetable(st_, dl, cap=8)
+    assert sched.unmeetable(st_, dl + 1e-9, cap=8)
     # With more waiting, the deadline scales the per-query reserve back up
     # by the expected batch size.
     for k in range(3):
         sched.enqueue("a", k, arrival_s=10.0)
-    assert sched.next_deadline(cap=8) == pytest.approx(
-        10.0 + 1.0 - 4 * st_.reserve_q_s)
+    dl = 10.0 + 1.0 - 4 * st_.reserve_q_s
+    assert not sched.unmeetable(st_, dl, cap=8)
+    assert sched.unmeetable(st_, dl + 1e-9, cap=8)
 
 
 def test_reserve_tracks_full_charged_window():

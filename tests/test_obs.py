@@ -4,7 +4,10 @@
   clock the test advances by hand, so nothing here reads the wall clock);
 * JAX's compiles count under the innermost open span, and only once;
 * ``ServedQuery.busy_wait_s`` splits a request's wait into the part the
-  server was busy and the part the batcher held it on an idle server;
+  server was busy and the part it sat idle (none: an idle server flushes
+  at once);
+* ``PerfModel.predict_rows`` reads back whole buckets, so a new row count
+  compiles nothing;
 * a model-backed ``serve()`` opens every span of the layer table;
 * served results do not depend on whether a profiler trace is running.
 """
@@ -13,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro import obs
+from repro.core.models.perf_model import NONDECISION_DIM
 from repro.core.moo.hmooc import HMOOCConfig
 from repro.queryengine.workloads import StreamRequest, make_benchmark
 from repro.serve import (OptimizerServer, RuntimeSession, ServerConfig,
@@ -124,13 +128,21 @@ def test_busy_wait_splits_the_wait_on_a_modelled_clock():
 
 
 def test_batcher_hold_on_an_idle_server_is_not_busy_wait():
-    # Two requests, a batch of 8: the batcher holds both on an idle server
-    # until the first one's deadline.
-    out = _served(8, [0.0, 0.1])
-    assert out[0].admitted_s > out[0].arrival_s
-    for s in out.values():
-        assert s.busy_wait_s == 0.0
-        assert s.flush_id == 0
+    # Two requests, a batch of 8: the idle server flushes the first at its
+    # arrival instead of holding it for company; the second arrives during
+    # that flush and waits only while the server is busy.
+    out = _served(8, [0.0, 0.01])
+    assert out[0].admitted_s == out[0].arrival_s
+    assert out[0].busy_wait_s == 0.0
+    assert out[1].admitted_s == out[1].arrival_s \
+        or out[1].admitted_s >= out[0].compiled_s
+    assert out[1].busy_wait_s == pytest.approx(
+        out[1].admitted_s - out[1].arrival_s)
+    assert [s.flush_id for s in out.values()] == [0, 1]
+    tr = out[0].trace
+    assert tr.counter("admission.flush.idle") >= 1
+    assert sum(tr.counter(f"admission.flush.{r}")
+               for r in ("idle", "full", "session")) == 2
 
 
 @pytest.fixture(scope="module")
@@ -189,6 +201,30 @@ def _outputs(s):
     return (s.ct.theta_c, s.ct.theta_p_sub, s.ct.theta_s_sub, s.ct.front,
             s.result.theta_p_eff, s.result.theta_s_eff, s.result.final_join,
             s.result.sim.ana_latency, s.result.sim.actual_latency)
+
+
+def test_predict_rows_compiles_nothing_for_a_new_row_count(models):
+    msub, _ = models
+    n = 64                                   # every count below is bucket 64
+    rng = np.random.default_rng(5)
+    emb = rng.normal(size=(n, msub.cfg.gtn.d_model)).astype(np.float32)
+    theta = rng.uniform(size=(n, msub.cfg.theta_dim)).astype(np.float32)
+    nond = rng.uniform(size=(n, NONDECISION_DIM)).astype(np.float32)
+    msub.predict_rows(emb, theta, nond)      # the bucket's head compiles here
+    with obs.record() as rec:
+        got = [msub.predict_rows(emb[:c], theta[:c], nond[:c])
+               for c in range(1, n + 1)]
+    assert rec.counter("model.dispatches.subq") == n
+    assert not {k: v for k, v in rec.counters.items()
+                if k.startswith("compiles@") and v}
+    # Bit for bit the rows of a slice taken on the device before the
+    # read-back.
+    for c, rows in enumerate(got, 1):
+        def pad(a):
+            return np.concatenate([a[:c], np.zeros((n - c, a.shape[1]),
+                                                   np.float32)])
+        z = msub._head(msub.params, pad(emb), pad(theta), pad(nond))
+        np.testing.assert_array_equal(rows, msub.from_z(np.asarray(z[:c])))
 
 
 def test_results_bit_identical_under_a_profiler_trace(models, tmp_path):

@@ -124,6 +124,38 @@ def test_repeat_serve_shares_caches(timed_stream, offline):
     assert srv.session.pool_cache.misses == 1
 
 
+@pytest.mark.parametrize("shape", ["gaps_over_budget", "burst_of_20"])
+def test_idle_server_flushes_at_once(shape):
+    """Work-conserving admission: a request that finds the server idle is
+    admitted at its arrival, and a burst flushes in batches of at most
+    ``max_batch``; results equal the offline reference either way."""
+    n = 20 if shape == "burst_of_20" else 6
+    gap = 0.0 if shape == "burst_of_20" else 3.0      # budget is 1.0 s
+    reqs = [StreamRequest(rid=i, query=q, arrival_s=i * gap)
+            for i, q in enumerate(serving_stream("tpch", n, seed=4))]
+    clock = ServiceTimeModel(flush_points=((1, 0.05), (8, 0.2)),
+                             round_s=0.01)
+    srv = _server(max_batch=8, clock=clock)
+    served = srv.serve(reqs)
+    queries = [r.query for r in reqs]
+    ref = RuntimeSession(weights=WEIGHTS).run_batch(
+        queries, TuningService(cfg=CFG).tune_batch(queries, WEIGHTS))
+    _assert_same_outputs(served, ref)
+    st = srv.last_run
+    sizes = [k for _, k in st.flush_windows]
+    assert sum(sizes) == n and max(sizes) <= 8
+    flushes = {r: st.trace.counter("admission.flush." + r)
+               for r in ("idle", "full", "session")}
+    assert sum(flushes.values()) == st.n_micro_batches
+    if shape == "gaps_over_budget":
+        assert all(s.admitted_s == s.arrival_s for s in served)
+        assert flushes["idle"] == n and sizes == [1] * n
+    else:
+        assert sizes[0] == 8 and flushes["full"] >= 1
+        assert all(s.busy_wait_s == pytest.approx(s.admitted_s - s.arrival_s)
+                   for s in served)
+
+
 # ---------------------------------------------------------------------------
 # Lifecycle / scheduling behavior
 # ---------------------------------------------------------------------------
