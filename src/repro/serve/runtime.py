@@ -262,6 +262,7 @@ class RuntimeSession:
         with obs.span("repro.runtime.aqe"):
             for e, cand, j in zip(waiting, cands, picks):
                 self._step(e, cand[j])
+        obs.count("runtime.requests", len(waiting))
         return len(waiting)
 
     def _live_gamma(self, e: _Entry, sq_id: int) -> np.ndarray:

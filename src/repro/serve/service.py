@@ -242,15 +242,17 @@ class TuningService:
         results: List[Optional[CompileTimeResult]] = [None] * len(queries)
         use_batched = (self._model is not None
                        and (self.jit_solve is None or self.jit_solve))
-        n_solved = n_cheap = n_default = 0
+        n_solved = n_subqs = n_cheap = n_default = 0
         run: List[int] = []
 
         def flush_run() -> None:
-            nonlocal n_solved
+            nonlocal n_solved, n_subqs
             if run:
                 # repro: allow[CK002] batched full solves store under the exact (non-degrade-marked) key on purpose — same contract as the direct put below; `degraded` never reaches _solve_run (degraded queries act as run barriers above)
-                n_solved += self._solve_run(queries, per_q_weights, tenants,
-                                            run, results)
+                solved = self._solve_run(queries, per_q_weights, tenants,
+                                         run, results)
+                n_solved += len(solved)
+                n_subqs += sum(queries[qi].n_subqs for qi in solved)
                 run.clear()
 
         for qi, (q, w) in enumerate(zip(queries, per_q_weights)):
@@ -281,11 +283,13 @@ class TuningService:
                 q, model=self._model, weights=w, cfg=self.cfg,
                 cost=self.cost, cache=self.cache)
             n_solved += 1
+            n_subqs += q.n_subqs
             if self._results is not None:
                 # repro: allow[CK002] full solves store under the exact key on purpose: degraded results are minted in _tune_cheap under degrade-marked keys, and an exact hit serving a later degraded request is the intended upgrade path
                 self._results.put(key, results[qi])
         flush_run()
         obs.count("solve.solved", n_solved)
+        obs.count("solve.subqs", n_subqs)
         dt = time.perf_counter() - t0
         self.last_batch = BatchStats(
             n_queries=len(queries), n_solved=n_solved,
@@ -310,7 +314,8 @@ class TuningService:
                    per_q_weights: Sequence[Weights],
                    tenants: Optional[Sequence[Optional[str]]],
                    idxs: Sequence[int],
-                   results: List[Optional[CompileTimeResult]]) -> int:
+                   results: List[Optional[CompileTimeResult]]
+                   ) -> List[int]:
         """Jitted micro-batch solve of one run of non-degraded queries.
 
         Semantically a transcript of the sequential loop: every
@@ -322,7 +327,7 @@ class TuningService:
         stage evaluations per solver phase are fused into one bucket-padded
         model call (:func:`fused_stage_eval`), and the HMOOC solves advance
         in lockstep as externally-driven :class:`HmoocPlan` state machines.
-        Returns the number of actual solves (post-dedup).
+        Returns the indices actually solved (post-dedup).
         """
         model = self._model
         with obs.span("repro.solve.lookup"):
@@ -461,7 +466,7 @@ class TuningService:
                         self._results.put(keys[qi], ct)
         for qi, key in deferred_gets:
             results[qi] = self._results.get(key)
-        return len(solved)
+        return solved
 
     def _tune_cheap(self, q: Query, w: Weights, exact_key: tuple
                     ) -> Tuple[CompileTimeResult, str]:

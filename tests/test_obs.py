@@ -9,7 +9,9 @@
 * ``PerfModel.predict_rows`` reads back whole buckets, so a new row count
   compiles nothing;
 * a model-backed ``serve()`` opens every span of the layer table;
-* served results do not depend on whether a profiler trace is running.
+* served results do not depend on whether a profiler trace is running;
+* ``solve.subqs`` counts the subQs of the requests actually solved and
+  ``runtime.requests`` what ``step_round`` serviced; neither opens a span.
 """
 import jax
 import numpy as np
@@ -239,3 +241,55 @@ def test_results_bit_identical_under_a_profiler_trace(models, tmp_path):
         assert (a.status, a.flush_id) == (b.status, b.flush_id)
         for x, y in zip(_outputs(a), _outputs(b)):
             np.testing.assert_array_equal(x, y)
+
+
+@pytest.mark.parametrize("jit_solve", [None, False],
+                         ids=["batched", "sequential"])
+def test_solve_subqs_counts_the_subqs_of_solved_requests(models, jit_solve):
+    msub, _ = models
+    svc = TuningService(model=msub, cfg=CFG, jit_solve=jit_solve)
+    a, b, c, d = (make_benchmark("tpch")[i] for i in (8, 4, 2, 6))
+    assert len({a.n_subqs, b.n_subqs, c.n_subqs}) == 3
+    with obs.record() as rec:
+        svc.tune_batch([a, b, a])          # a again in the same run
+        svc.tune_batch([b, c])             # b from the response cache
+        svc.tune_batch([d], degraded=[True])
+    assert svc.totals.n_solved == 3
+    assert rec.counter("solve.solved") == 3
+    assert rec.counter("solve.subqs") == a.n_subqs + b.n_subqs + c.n_subqs
+
+
+def test_runtime_requests_counts_what_step_round_services(models):
+    msub, mqs = models
+    qs = make_benchmark("tpch")[:3]
+    cts = TuningService(model=msub, cfg=CFG).tune_batch(qs)
+    session = RuntimeSession(model_subq=msub, model_qs=mqs, weights=WEIGHTS)
+    serviced = []
+    with obs.record() as rec:
+        for q, ct in zip(qs, cts):
+            session.admit(q, ct)
+        while n := session.step_round():
+            serviced.append(n)
+    assert len(serviced) > 1
+    assert rec.counter("runtime.requests") == sum(serviced)
+    # One per LQP or QS request sent; the pruned ones are never serviced.
+    done = session.realize(session.retire_ready())
+    assert sum(serviced) == sum(r.lqp_requests_sent + r.qs_requests_sent
+                                for r in done)
+
+
+def test_new_counters_open_no_span(models, monkeypatch):
+    reqs = _requests(ARRIVALS, n_first=8)
+    _model_server(models).serve(reqs)      # fills the models' embedding memo
+    counted = _model_server(models).serve(reqs)[0].trace
+    new = ("solve.subqs", "runtime.requests")
+    count = obs.count
+    monkeypatch.setattr(obs, "count",
+                        lambda name, n=1: name in new or count(name, n))
+    uncounted = _model_server(models).serve(reqs)[0].trace
+    assert {k: v[0] for k, v in counted.spans.items()} == \
+        {k: v[0] for k, v in uncounted.spans.items()}
+    assert counted.counter("solve.subqs") == \
+        sum(r.query.n_subqs for r in reqs)
+    assert counted.counter("runtime.requests") > 0
+    assert not any(uncounted.counter(k) for k in new)
