@@ -341,7 +341,7 @@ def run_overload(bench: str = "tpch", n: int = 96,
     from repro.core.moo import hmooc as hmooc_mod
     bank_builds = [0]
     degraded_bank_builds = [0]
-    orig_opt = hmooc_mod._optimize_rep_banks
+    orig_opt = hmooc_mod._rep_banks
     orig_cheap = srv.tuning._tune_cheap
 
     def _counting_opt(*a, **kw):
@@ -354,12 +354,12 @@ def run_overload(bench: str = "tpch", n: int = 96,
         degraded_bank_builds[0] += bank_builds[0] - before
         return out
 
-    hmooc_mod._optimize_rep_banks = _counting_opt
+    hmooc_mod._rep_banks = _counting_opt
     srv.tuning._tune_cheap = _counting_cheap
     try:
         served = srv.serve(reqs)
     finally:
-        hmooc_mod._optimize_rep_banks = orig_opt
+        hmooc_mod._rep_banks = orig_opt
         srv.tuning._tune_cheap = orig_cheap
     rep = srv.latency_report(served)
     totals = srv.tuning.totals

@@ -46,8 +46,8 @@ from . import pareto as _pareto
 from .pareto import _f32_tie_hazard, pareto_mask_fast, pareto_mask_np
 
 __all__ = ["HMOOCConfig", "HMOOCResult", "EffectiveSet", "hmooc_solve",
-           "HmoocPlan", "subq_tuning", "build_candidates", "dag_aggregate",
-           "minkowski_merge_2d"]
+           "HmoocPlan", "StageRows", "subq_tuning", "build_candidates",
+           "dag_aggregate", "minkowski_merge_2d"]
 
 StageEval = Callable[[int, np.ndarray, np.ndarray], np.ndarray]
 
@@ -201,14 +201,49 @@ def build_candidates(
     return EffectiveSet(Uc=Uc, labels=labels, reps=reps, pool=pool)
 
 
-def _rep_bank_requests(m: int, eset: EffectiveSet
-                       ) -> List[Tuple[int, np.ndarray, np.ndarray]]:
-    """The stage-eval rows of the representative-MOO phase, per subQ."""
-    reps, pool = eset.reps, eset.pool
-    C, P = reps.shape[0], pool.shape[0]
-    Tc = np.repeat(reps, P, axis=0)
-    Tp = np.tile(pool, (C, 1))
-    return [(i, Tc, Tp) for i in range(m)]
+@dataclasses.dataclass(frozen=True, eq=False)
+class StageRows:
+    """One subQ's stage rows in a solve phase, by θc candidate.
+
+    Row j is θc = ``cands[cidx[j]]`` ⊕ θp⊕θs = ``Tps[j]``.  A phase's rows
+    repeat a handful of θc candidates, so work that depends on θc alone is
+    done once per candidate and gathered by ``cidx``; the bank phase hands
+    one object to every subQ.
+    """
+    cands: np.ndarray      # (C, d_c) unit θc candidates
+    cidx: np.ndarray       # (n,) each row's candidate
+    Tps: np.ndarray        # (n, d_ps) unit θp⊕θs rows
+
+    @property
+    def Tc(self) -> np.ndarray:
+        """(n, d_c) unit θc rows."""
+        return self.cands[self.cidx]
+
+
+def _rep_bank_rows(eset: EffectiveSet) -> StageRows:
+    """The stage rows of the representative-MOO phase, shared by every subQ."""
+    C, P = eset.reps.shape[0], eset.pool.shape[0]
+    return StageRows(eset.reps, np.repeat(np.arange(C), P),
+                     np.tile(eset.pool, (C, 1)))
+
+
+def _rep_banks(Fs: Sequence[np.ndarray], eset: EffectiveSet,
+               cfg: HMOOCConfig) -> Tuple[List[List[np.ndarray]], int, int]:
+    """Line 3's banks from each subQ's objectives on :func:`_rep_bank_rows`.
+
+    Returns (opt_idx [C][m], k_obj, n_evals).
+    """
+    C, P = eset.reps.shape[0], eset.pool.shape[0]
+    opt_idx: List[List[np.ndarray]] = [[] for _ in range(C)]
+    k_obj = 2
+    n_evals = 0
+    for F in Fs:
+        n_evals += F.shape[0]
+        k_obj = F.shape[1]
+        Fr = F.reshape(C, P, k_obj)
+        for r in range(C):
+            opt_idx[r].append(_pareto_bank(Fr[r], cfg.max_bank))
+    return opt_idx, k_obj, n_evals
 
 
 def _optimize_rep_banks(
@@ -221,30 +256,23 @@ def _optimize_rep_banks(
 
     Returns (opt_idx [C][m], k_obj, n_evals).
     """
-    C, P = eset.reps.shape[0], eset.pool.shape[0]
-    opt_idx: List[List[np.ndarray]] = [[] for _ in range(C)]
-    k_obj = 2
-    n_evals = 0
-    for i, Tc, Tp in _rep_bank_requests(m, eset):
-        F = stage_eval(i, Tc, Tp)
-        n_evals += F.shape[0]
-        k_obj = F.shape[1]
-        Fr = F.reshape(C, P, k_obj)
-        for r in range(C):
-            opt_idx[r].append(_pareto_bank(Fr[r], cfg.max_bank))
-    return opt_idx, k_obj, n_evals
+    rows = _rep_bank_rows(eset)
+    Tc = rows.Tc
+    return _rep_banks([stage_eval(i, Tc, rows.Tps) for i in range(m)],
+                      eset, cfg)
 
 
-def _assign_requests(m: int, eset: EffectiveSet, cfg: HMOOCConfig) -> List[
-        Optional[Tuple[np.ndarray, np.ndarray,
-                       List[Tuple[np.ndarray, np.ndarray]]]]]:
-    """Per-subQ (θc rows, θp⊕θs rows, scatter chunks) of the assign phase.
+AssignRequest = Tuple[StageRows, List[Tuple[np.ndarray, np.ndarray]]]
+
+
+def _assign_requests(m: int, eset: EffectiveSet, cfg: HMOOCConfig
+                     ) -> List[Optional[AssignRequest]]:
+    """Per-subQ (stage rows, scatter chunks) of the assign phase.
 
     Entry i is None when subQ i has nothing to evaluate (no members or all
-    banks empty).  Deterministic in ``eset``: rebuilding the requests for
-    the same effective set yields the same rows, which is what lets a batch
-    driver evaluate them externally and replay the results into
-    :func:`_assign_banks`.
+    banks empty).  The rows' candidates are ``eset.Uc``; chunk
+    ``(members, sel)`` covers every member of one cluster against its
+    representative's bank ``sel``, member-major.
     """
     Uc, labels, pool = eset.Uc, eset.labels, eset.pool
     opt_idx = eset.opt_idx
@@ -252,7 +280,7 @@ def _assign_requests(m: int, eset: EffectiveSet, cfg: HMOOCConfig) -> List[
     C = eset.reps.shape[0]
     B = cfg.max_bank
     members_by_rep = [np.nonzero(labels == r)[0] for r in range(C)]
-    out = []
+    out: List[Optional[AssignRequest]] = []
     for i in range(m):
         rows_c: List[np.ndarray] = []
         rows_p: List[np.ndarray] = []
@@ -269,9 +297,42 @@ def _assign_requests(m: int, eset: EffectiveSet, cfg: HMOOCConfig) -> List[
         if not chunks:
             out.append(None)
             continue
-        out.append((Uc[np.concatenate(rows_c)],
-                    pool[np.concatenate(rows_p)], chunks))
+        out.append((StageRows(Uc, np.concatenate(rows_c),
+                              pool[np.concatenate(rows_p)]), chunks))
     return out
+
+
+def _assign_scatter(
+    reqs: Sequence[Optional[AssignRequest]],
+    Fs: Sequence[np.ndarray],
+    N: int,
+    cfg: HMOOCConfig,
+    k_obj: int,
+) -> Tuple[np.ndarray, np.ndarray, int]:
+    """Lines 4/7's banks: scatter each subQ's objectives on its
+    :func:`_assign_requests` rows into ``(F_bank, idx_bank)``.
+
+    ``Fs`` holds one array per non-None request, in subQ order.
+    """
+    m, B = len(reqs), cfg.max_bank
+    F_bank = np.full((N, m, B, k_obj), np.inf)
+    idx_bank = np.full((N, m, B), -1, int)
+    n_evals = 0
+    it = iter(Fs)
+    for i, req in enumerate(reqs):
+        if req is None:
+            continue
+        F = next(it)
+        n_evals += F.shape[0]
+        off = 0
+        for members, sel in req[1]:
+            nb = sel.size
+            cnt = members.size * nb
+            F_bank[members, i, :nb] = \
+                F[off:off + cnt].reshape(members.size, nb, k_obj)
+            idx_bank[members, i, :nb] = sel
+            off += cnt
+    return F_bank, idx_bank, n_evals
 
 
 def _assign_banks(
@@ -285,25 +346,10 @@ def _assign_banks(
 
     One stage_eval per subQ covering every (member, bank slot) pair at once.
     """
-    N, B = eset.Uc.shape[0], cfg.max_bank
-    F_bank = np.full((N, m, B, k_obj), np.inf)
-    idx_bank = np.full((N, m, B), -1, int)
-    n_evals = 0
-    for i, req in enumerate(_assign_requests(m, eset, cfg)):
-        if req is None:
-            continue
-        Tc_rows, Tp_rows, chunks = req
-        F = stage_eval(i, Tc_rows, Tp_rows)
-        n_evals += F.shape[0]
-        off = 0
-        for members, sel in chunks:
-            nb = sel.size
-            cnt = members.size * nb
-            F_bank[members, i, :nb] = \
-                F[off:off + cnt].reshape(members.size, nb, k_obj)
-            idx_bank[members, i, :nb] = sel
-            off += cnt
-    return F_bank, idx_bank, n_evals
+    reqs = _assign_requests(m, eset, cfg)
+    Fs = [stage_eval(i, req[0].Tc, req[0].Tps)
+          for i, req in enumerate(reqs) if req is not None]
+    return _assign_scatter(reqs, Fs, eset.Uc.shape[0], cfg, k_obj)
 
 
 def subq_tuning(
@@ -644,14 +690,13 @@ class HmoocPlan:
     query, fuses every plan's pending requests into a single batched model
     dispatch per round, and feeds the results back — so a micro-batch of M
     queries costs two regressor calls total instead of 2·M·m.  The
-    arithmetic is :func:`hmooc_solve`'s exactly: each phase replays the fed
-    results through the same :func:`_optimize_rep_banks` /
-    :func:`_assign_banks` the sequential solve calls (request row-building
-    is deterministic in the effective set, so the replayed rows are the
-    rows the results were computed on).
+    arithmetic is :func:`hmooc_solve`'s exactly: each phase's rows are built
+    once, by the same :func:`_rep_bank_rows` / :func:`_assign_requests` the
+    sequential solve calls, and the fed results go through the same
+    :func:`_rep_banks` / :func:`_assign_scatter`.
 
     Protocol: while ``not plan.done``, call ``requests()`` (a list of
-    ``(i, Tc, Tps)`` stage requests), evaluate them externally, and pass
+    ``(i, StageRows)`` stage requests), evaluate them externally, and pass
     the aligned objective arrays to ``feed()``.  ``banks_ready`` flips
     after the first phase, at which point ``eset`` carries the optimal-θp
     banks — a driver hands it to same-template plans to reuse, mirroring a
@@ -680,7 +725,8 @@ class HmoocPlan:
         else:
             self.k_obj = 2
             self._phase = "banks"
-        self._reqs: Optional[List[Tuple[int, np.ndarray, np.ndarray]]] = None
+        self._reqs: Optional[List[Tuple[int, StageRows]]] = None
+        self._assign: Optional[List[Optional[AssignRequest]]] = None
 
     @property
     def done(self) -> bool:
@@ -690,18 +736,17 @@ class HmoocPlan:
     def banks_ready(self) -> bool:
         return self._phase in ("assign", "done")
 
-    def requests(self) -> List[Tuple[int, np.ndarray, np.ndarray]]:
-        # Row-building is deterministic in (eset, cfg), so the per-phase
-        # request list is memoized: the driver calls this once to collect
-        # work and feed() consumes it again to align results.
+    def requests(self) -> List[Tuple[int, StageRows]]:
+        # Memoized per phase: the caller collects work from it once and
+        # feed() reads the same rows (and the assign phase's chunks) back.
         if self._reqs is not None:
             return self._reqs
         if self._phase == "banks":
-            self._reqs = _rep_bank_requests(self.m, self.eset)
+            rows = _rep_bank_rows(self.eset)
+            self._reqs = [(i, rows) for i in range(self.m)]
         elif self._phase == "assign":
-            self._reqs = [(i, req[0], req[1]) for i, req in
-                          enumerate(_assign_requests(self.m, self.eset,
-                                                     self.cfg))
+            self._assign = _assign_requests(self.m, self.eset, self.cfg)
+            self._reqs = [(i, req[0]) for i, req in enumerate(self._assign)
                           if req is not None]
         else:
             raise RuntimeError("plan is already done")
@@ -709,23 +754,20 @@ class HmoocPlan:
 
     def feed(self, results: Sequence[np.ndarray]) -> None:
         """Advance one phase with the objective arrays for ``requests()``."""
-        fmap = {i: F for (i, _, _), F in zip(self.requests(), results)}
-
-        def replay(i, Tc, Tps):
-            return fmap[i]
-
+        self.requests()
+        self._reqs = None
         if self._phase == "banks":
-            opt_idx, k_obj, n1 = _optimize_rep_banks(replay, self.m,
-                                                     self.eset, self.cfg)
+            opt_idx, k_obj, n1 = _rep_banks(results, self.eset, self.cfg)
             self.eset = dataclasses.replace(self.eset, opt_idx=opt_idx,
                                             k_obj=k_obj)
             self.k_obj = k_obj
             self.n_evals += n1
             self._phase = "assign"
-            self._reqs = None
             return
-        F_bank, idx_bank, n2 = _assign_banks(replay, self.m, self.eset,
-                                             self.cfg, self.k_obj)
+        F_bank, idx_bank, n2 = _assign_scatter(
+            self._assign, results, self.eset.Uc.shape[0], self.cfg,
+            self.k_obj)
+        self._assign = None
         self.n_evals += n2
         front, theta_c, theta_ps = dag_aggregate(
             self.eset.Uc, self.eset.pool, F_bank, idx_bank,
@@ -737,4 +779,3 @@ class HmoocPlan:
                     "reused_banks": float(self.reused_banks)},
             effective_set=self.eset)
         self._phase = "done"
-        self._reqs = None

@@ -18,14 +18,16 @@ list-structured DAG aggregation (paper §5.1.2).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from ... import obs
 from ...queryengine.plan import Query
 from ...queryengine.simulator import CostModel, DEFAULT_COST, simulate_subq
 from ...queryengine.trace import _alpha_stats
 from ..models.perf_model import PerfModel, make_nondecision
+from ..moo.hmooc import StageRows
 from .spark_space import theta_c_space, theta_p_space, theta_s_space
 
 __all__ = ["StageObjectives", "resource_rate", "QueryObjective",
@@ -136,57 +138,87 @@ class StageObjectives:
 
 QueryObjective = Callable[[np.ndarray], np.ndarray]
 
-# One stage-evaluation request: (objectives, subQ index, θc rows, θp⊕θs rows).
-StageRequest = Tuple["StageObjectives", int, np.ndarray, np.ndarray]
+# One stage-evaluation request: (objectives, subQ index, stage rows), or
+# (objectives, subQ index, θc rows, θp⊕θs rows) with every row its own θc
+# candidate.
+StageRequest = Union[Tuple["StageObjectives", int, StageRows],
+                     Tuple["StageObjectives", int, np.ndarray, np.ndarray]]
+
+
+def _stage_rows(item: StageRequest) -> StageRows:
+    if len(item) == 3:
+        return item[2]
+    Tc = item[2]
+    return StageRows(Tc, np.arange(Tc.shape[0]), item[3])
 
 
 def fused_stage_eval(items: Sequence[StageRequest]) -> List[np.ndarray]:
     """Evaluate many stage requests — across subQs *and* queries — at once.
 
-    The model-backed path concatenates every request's regressor rows
+    The model-backed path writes every request's regressor rows
     (per-row embedding ⊕ θ ⊕ nondecision) into a single bucket-padded
     :meth:`PerfModel.predict_rows` dispatch, then finishes the float64
-    latency→dollars arithmetic per request.  Per-request outputs are
-    identical to calling ``obj.stage_eval(i, Tc, Tps)`` one by one: row j of
-    a padded batch equals row j of the per-request call, and the cost
-    arithmetic is element-wise.  All requests must share one model (the
-    serving layer batches per service); the oracle backend (``model is
-    None``) falls back to per-request evaluation, which is already one
-    vectorized simulator call each.
+    latency→dollars arithmetic per request.  Work that depends on θc alone
+    is done once per distinct candidate set, not per row: each request's
+    θ rows are written once per :class:`StageRows` object (the bank phase
+    shares one across subQs), and the θc unit→raw conversion and resource
+    rate once per (objectives, candidates) pair, gathered by row
+    (counter ``solve.cost_rows``: θc rows converted).  Per-request outputs
+    are identical to calling ``obj.stage_eval(i, Tc, Tps)`` one by one:
+    row j of a padded batch equals row j of the per-request call, and the
+    cost arithmetic is element-wise.  All requests must share one model
+    (the serving layer batches per service); the oracle backend
+    (``model is None``) falls back to per-request evaluation, which is
+    already one vectorized simulator call each.
     """
     if not items:
         return []
-    model = items[0][0].model
+    reqs = [(it[0], it[1], _stage_rows(it)) for it in items]
+    model = reqs[0][0].model
     if model is None:
-        return [obj.stage_eval(i, Tc, Tps) for obj, i, Tc, Tps in items]
-    if any(it[0].model is not model for it in items):
+        return [obj.stage_eval(i, r.Tc, r.Tps) for obj, i, r in reqs]
+    if any(obj.model is not model for obj, _, _ in reqs):
         raise ValueError("fused_stage_eval requires one shared model")
-    thetas, metas = [], []
-    for obj, i, Tc, Tps in items:
-        tc_raw, _, _ = obj.split_raw(Tc, Tps)
-        theta = obj.theta_rows(Tc, Tps)
-        thetas.append(theta)
-        metas.append((obj, i, theta.shape[0], tc_raw))
-    total = sum(n for _, _, n, _ in metas)
-    emb0 = items[0][0]._embs[items[0][1]]
-    nond0 = items[0][0]._nond[items[0][1]]
+    obj0, i0, r0 = reqs[0]
+    d_c = r0.cands.shape[1]
+    total = sum(r.cidx.shape[0] for _, _, r in reqs)
     # Per-row emb/nond are broadcast straight into the dispatch buffers —
     # no per-request np.repeat intermediates on the host.
-    emb_all = np.empty((total, emb0.shape[0]), np.float32)
-    nond_all = np.empty((total, nond0.shape[0]), np.float32)
+    emb_all = np.empty((total, obj0._embs[i0].shape[0]), np.float32)
+    theta_all = np.empty((total, d_c + r0.Tps.shape[1]), np.float32)
+    nond_all = np.empty((total, obj0._nond[i0].shape[0]), np.float32)
+    first: dict = {}   # rows -> offset of its θ rows in theta_all
+    per_c: dict = {}   # (obj, id(cands)) -> (f32 θc, $/s) per candidate
+    rates = []
     off = 0
-    for obj, i, n, _ in metas:
+    for obj, i, r in reqs:
+        n = r.cidx.shape[0]
         emb_all[off:off + n] = obj._embs[i]
         nond_all[off:off + n] = obj._nond[i]
+        # repro: allow[RP004] within-call grouping token: rows sharing a candidate array share its conversion, outputs are identical either way, and the key never leaves this call
+        key = (obj, id(r.cands))
+        c = per_c.get(key)
+        if c is None:
+            c = per_c[key] = (r.cands.astype(np.float32),
+                              resource_rate(obj.cs.to_raw(r.cands), obj.cost))
+            obs.count("solve.cost_rows", r.cands.shape[0])
+        src = first.get(r)
+        if src is None:
+            first[r] = off
+            theta_all[off:off + n, :d_c] = c[0][r.cidx]
+            theta_all[off:off + n, d_c:] = r.Tps
+        else:
+            theta_all[off:off + n] = theta_all[src:src + n]
+        rates.append(c[1])
         off += n
-    pred = model.predict_rows(emb_all, np.concatenate(thetas, 0), nond_all)
+    pred = model.predict_rows(emb_all, theta_all, nond_all)
     out: List[np.ndarray] = []
     off = 0
-    for obj, _, n, tc_raw in metas:
+    for (obj, _, r), rate in zip(reqs, rates):
+        n = r.cidx.shape[0]
         p = pred[off:off + n]
         off += n
         lat, io = p[:, 0], p[:, 1]
-        dollars = lat * resource_rate(tc_raw, obj.cost) \
-            + io * obj.cost.price_io_gb
+        dollars = lat * rate[r.cidx] + io * obj.cost.price_io_gb
         out.append(np.stack([lat, dollars], -1))
     return out

@@ -414,8 +414,8 @@ class TuningService:
                     items, spans = [], []
                     for qi in active:
                         reqs = plans[qi].requests()
-                        items.extend((objs[qi], i, Tc, Tps)
-                                     for i, Tc, Tps in reqs)
+                        items.extend((objs[qi], i, rows)
+                                     for i, rows in reqs)
                         spans.append((qi, len(reqs)))
                     evals = fused_stage_eval(items)
                 # Feed by phase: Algorithm-1 bank builds, then the assign

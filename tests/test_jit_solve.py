@@ -193,3 +193,77 @@ def test_fused_stage_eval_oracle_fallback():
     got = fused_stage_eval([(obj, 0, Tc, Tps), (obj, 1, Tc, Tps)])
     np.testing.assert_array_equal(got[0], obj.stage_eval(0, Tc, Tps))
     np.testing.assert_array_equal(got[1], obj.stage_eval(1, Tc, Tps))
+
+
+@pytest.mark.parametrize("bench, qi", [("tpch", 2), ("tpcds", 87)])
+def test_plan_rows_per_candidate_bitmatch(smoke_perf_models, bench, qi):
+    """A model-backed HmoocPlan at the default config, driven phase by
+    phase through fused_stage_eval: every phase's outputs equal the
+    per-request stage_eval on the expanded rows bit for bit, the θc
+    conversion runs once per distinct candidate of the phase (not per row),
+    and the final front and θ equal hmooc_solve's."""
+    from repro.core.moo.hmooc import HmoocPlan, hmooc_solve
+    model = smoke_perf_models["subq"]
+    q = make_benchmark(bench)[qi]
+    if bench == "tpcds":
+        assert q.n_subqs == 48
+    cfg = HMOOCConfig()
+    obj = StageObjectives(q, model=model)
+    plan = HmoocPlan(obj.m, obj.d_c, obj.d_ps, cfg, snap_c=obj.snap_c,
+                     snap_ps=obj.snap_ps)
+    phases = 0
+    while not plan.done:
+        reqs = plan.requests()
+        cands = {id(rows.cands): rows.cands.shape[0] for _, rows in reqs}
+        n_rows = sum(rows.cidx.shape[0] for _, rows in reqs)
+        with obs.record() as rec:
+            got = fused_stage_eval([(obj, i, rows) for i, rows in reqs])
+        assert rec.counter("model.rows.subq") == n_rows
+        assert rec.counter("solve.cost_rows") == sum(cands.values())
+        assert len(cands) == 1
+        expect = (plan.eset.Uc if plan.banks_ready else plan.eset.reps)
+        assert sum(cands.values()) == expect.shape[0] < n_rows
+        for (i, rows), F in zip(reqs, got):
+            np.testing.assert_array_equal(
+                F, obj.stage_eval(i, rows.Tc, rows.Tps))
+        plan.feed(got)
+        phases += 1
+    assert phases == 2
+    ref = hmooc_solve(obj.stage_eval, obj.m, obj.d_c, obj.d_ps, cfg,
+                      snap_c=obj.snap_c, snap_ps=obj.snap_ps)
+    np.testing.assert_array_equal(plan.result.front, ref.front)
+    np.testing.assert_array_equal(plan.result.theta_c, ref.theta_c)
+    np.testing.assert_array_equal(plan.result.theta_ps, ref.theta_ps)
+    assert plan.result.n_evals == ref.n_evals
+
+
+def test_fused_stage_eval_plain_rows_count_each_row(smoke_perf_models):
+    """Plain (θc, θp⊕θs) rows are every row its own candidate: the cost
+    counter then equals the rows dispatched."""
+    model = smoke_perf_models["subq"]
+    obj = StageObjectives(make_benchmark("tpch")[1], model=model)
+    rng = np.random.default_rng(1)
+    items = [(obj, i, rng.random((5 + i, obj.d_c)),
+              rng.random((5 + i, obj.d_ps))) for i in range(2)]
+    with obs.record() as rec:
+        fused_stage_eval(items)
+    assert rec.counter("solve.cost_rows") == \
+        rec.counter("model.rows.subq") == 11
+
+
+def test_plan_builds_assign_rows_once(smoke_perf_models, monkeypatch):
+    """A served solve builds its assign phase's rows once: feed() scatters
+    with the chunks requests() kept, not a rebuild."""
+    from repro.core.moo import hmooc as hmooc_mod
+    calls = []
+    orig = hmooc_mod._assign_requests
+
+    def spy(*a, **kw):
+        calls.append(1)
+        return orig(*a, **kw)
+
+    monkeypatch.setattr(hmooc_mod, "_assign_requests", spy)
+    svc = TuningService(model=smoke_perf_models["subq"], cfg=CFG)
+    svc.tune_batch([make_benchmark("tpch")[3]])
+    assert svc.last_batch.n_solved == 1
+    assert len(calls) == 1

@@ -404,8 +404,8 @@ def test_overload_shed_degrade_survivors_bit_identical():
 def test_degraded_path_never_runs_fresh_algorithm1(monkeypatch):
     """Zero fresh Algorithm 1 bank builds for degraded queries: with a warm
     template cache the banks are reused across variants; with a cold cache
-    the Spark-default θ is served — `_optimize_rep_banks` must not run
-    either way."""
+    the Spark-default θ is served — `_rep_banks`, which every bank build
+    (sequential or batched) goes through, must not run either way."""
     from repro.core.moo import hmooc as hmooc_mod
     spec = TenantSpec(name="deg", slo="degrade", solve_budget_s=0.0,
                       arrivals=ArrivalModel(kind="poisson", rate_qps=50.0))
@@ -413,13 +413,13 @@ def test_degraded_path_never_runs_fresh_algorithm1(monkeypatch):
     srv = OptimizerServer(config=ServerConfig(max_batch=3), weights=WEIGHTS,
                           cfg=CFG, tenants=[spec])
     calls = []
-    orig = hmooc_mod._optimize_rep_banks
+    orig = hmooc_mod._rep_banks
 
     def spy(*a, **kw):
         calls.append(1)
         return orig(*a, **kw)
 
-    monkeypatch.setattr(hmooc_mod, "_optimize_rep_banks", spy)
+    monkeypatch.setattr(hmooc_mod, "_rep_banks", spy)
     served = srv.serve(reqs)
     assert [s.status for s in served] == ["degraded"] * 6
     assert all(s.result is not None for s in served)
@@ -430,11 +430,11 @@ def test_degraded_path_never_runs_fresh_algorithm1(monkeypatch):
     # Now warm the template cache with full solves of the same queries and
     # serve the degraded stream again: cheap solves reuse the banks — and
     # still zero fresh Algorithm 1 runs for the degraded traffic.
-    monkeypatch.setattr(hmooc_mod, "_optimize_rep_banks", orig)
+    monkeypatch.setattr(hmooc_mod, "_rep_banks", orig)
     queries = list({s.request.query.qid: s.request.query
                     for s in served}.values())
     srv.tuning.tune_batch(queries, WEIGHTS)          # full-quality warmup
-    monkeypatch.setattr(hmooc_mod, "_optimize_rep_banks", spy)
+    monkeypatch.setattr(hmooc_mod, "_rep_banks", spy)
     srv2_reqs = multi_tenant_stream("tpch", [spec], 6, seed=14)
     served2 = srv.serve(srv2_reqs)
     assert [s.status for s in served2] == ["degraded"] * 6
