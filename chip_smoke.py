@@ -11,9 +11,9 @@ The phases run in this order, and any failure raises (exit code != 0):
    compiled for the chip (a Mosaic ``tpu_custom_call`` in the compiled
    program) at serving shapes, must equal their references.
 3. models  -- the ``subq`` and ``qs`` PerfModels at their default widths,
-   trained from seeded TPC-H traces with the benchmarks' recipe
-   (``benchmarks/common.py``: 3 variants x 32 configurations, batch 512),
-   for fewer steps than its 1500.  Their embeddings and predictions on a
+   trained from seeded TPC-H traces with the benchmark's recipe
+   (3 variants x 32 configurations, batch 512), for fewer steps than its
+   1500.  Their embeddings and predictions on a
    fixed sample of trace rows must agree with the host CPU's, same
    parameters, to ``DEVICE_CPU_RTOL``.
 4. serve   -- an OptimizerServer at ``HMOOCConfig()`` defaults serves a
@@ -204,7 +204,7 @@ def run_kernels(seed: int, *, interpret: bool,
 # ---------------------------------------------------------------------------
 
 def smoke_traces(variants: int = 3, confs: int = 32) -> TraceSet:
-    """Seeded TPC-H traces (``benchmarks/common.py``'s recipe)."""
+    """Seeded TPC-H traces (3 variants x 32 configurations)."""
     queries = default_workload("tpch", variants, seed=SEED)
     return collect_traces(queries, confs, seed=SEED)
 

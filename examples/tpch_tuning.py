@@ -2,22 +2,22 @@
 
     PYTHONPATH=src python examples/tpch_tuning.py [--model]
 
-``--model`` uses the trained GTN models (trains/caches them on first use —
-minutes); default uses oracle objectives for a fast demonstration.
+``--model`` first trains the GTN subQ model on seeded TPC-H traces (3
+variants x 32 configurations, 1500 steps — minutes); the default uses
+oracle objectives for a fast demonstration.
 """
 import argparse
-import sys
 
 import numpy as np
 
-sys.path.insert(0, ".")  # for benchmarks.* when run from the repo root
-
+from repro.core.models.training import build_dataset, train_model
 from repro.core.moo.hmooc import HMOOCConfig
 from repro.core.tuning.compile_time import compile_time_optimize
 from repro.core.tuning.runtime import make_runtime_optimizers
 from repro.queryengine.aqe import run_with_aqe
 from repro.queryengine.simulator import default_theta
-from repro.queryengine.workloads import make_benchmark
+from repro.queryengine.trace import collect_traces
+from repro.queryengine.workloads import default_workload, make_benchmark
 
 
 def main() -> None:
@@ -29,8 +29,10 @@ def main() -> None:
 
     model = None
     if args.model:
-        from benchmarks.common import get_model
-        model = get_model("tpch", "subq")[0]
+        traces = collect_traces(default_workload("tpch", 3, seed=0), 32,
+                                seed=0)
+        ds, mcfg = build_dataset(traces, "subq", seed=0)
+        model = train_model(ds, mcfg, steps=1500, batch=512, seed=0)
 
     lat_d, lat_o, st = [], [], []
     for q in make_benchmark("tpch"):

@@ -131,7 +131,7 @@ class PerfModel:
         self._emb_cache: Dict[Any, np.ndarray] = {}
         self._fp: Optional[str] = None
         # Shape buckets seen by the padded batch paths (the recompilation
-        # bound the serving benchmarks assert against).
+        # bound the tests assert against).
         self.head_buckets: set = set()
         self.embed_buckets: set = set()
 

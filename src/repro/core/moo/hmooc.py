@@ -46,7 +46,7 @@ from . import pareto as _pareto
 from .pareto import _f32_tie_hazard, pareto_mask_fast, pareto_mask_np
 
 __all__ = ["HMOOCConfig", "HMOOCResult", "EffectiveSet", "hmooc_solve",
-           "HmoocPlan", "StageRows", "subq_tuning", "build_candidates",
+           "HmoocPlan", "StageRows", "build_candidates",
            "dag_aggregate", "minkowski_merge_2d"]
 
 StageEval = Callable[[int, np.ndarray, np.ndarray], np.ndarray]
@@ -350,33 +350,6 @@ def _assign_banks(
     Fs = [stage_eval(i, req[0].Tc, req[0].Tps)
           for i, req in enumerate(reqs) if req is not None]
     return _assign_scatter(reqs, Fs, eset.Uc.shape[0], cfg, k_obj)
-
-
-def subq_tuning(
-    stage_eval: StageEval,
-    m: int,
-    d_c: int,
-    d_ps: int,
-    cfg: HMOOCConfig,
-    *,
-    snap_c=None,
-    snap_ps=None,
-    rng: Optional[np.random.Generator] = None,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int]:
-    """Effective-set generation (Algorithm 1).
-
-    Returns (Uc, pool, F_bank, idx_bank, n_evals) where
-      Uc: (N, d_c) θc candidates,
-      pool: (P, d_ps) shared θp⊕θs samples,
-      F_bank: (N, m, B, k) objective values (+inf padded),
-      idx_bank: (N, m, B) pool indices (−1 padded).
-    """
-    eset = build_candidates(d_c, d_ps, cfg, snap_c=snap_c, snap_ps=snap_ps,
-                            rng=rng)
-    opt_idx, k_obj, n1 = _optimize_rep_banks(stage_eval, m, eset, cfg)
-    eset.opt_idx, eset.k_obj = opt_idx, k_obj
-    F_bank, idx_bank, n2 = _assign_banks(stage_eval, m, eset, cfg, k_obj)
-    return eset.Uc, eset.pool, F_bank, idx_bank, n1 + n2
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ Three implementations of dominance filtering are provided:
   (imported lazily in :func:`pareto_mask_fast` to avoid circular imports).
 
 Also includes Kung's O(n log n) algorithm for k=2 (host-side oracle) and
-hypervolume computation used by the benchmarks.
+2-objective hypervolume.
 """
 from __future__ import annotations
 
@@ -33,7 +33,6 @@ __all__ = [
     "filter_dominated_np",
     "compact_bank",
     "hypervolume_2d",
-    "hypervolume",
 ]
 
 def backend() -> str:
@@ -295,23 +294,3 @@ def hypervolume_2d(F: np.ndarray, ref: np.ndarray) -> float:
             hv += (ref[0] - f0) * (prev_f1 - f1)
             prev_f1 = f1
     return float(hv)
-
-
-def hypervolume(F: np.ndarray, ref: np.ndarray, n_mc: int = 200_000, seed: int = 0) -> float:
-    """Hypervolume for k objectives: exact for k=2, Monte-Carlo otherwise."""
-    F = np.asarray(F, np.float64)
-    ref = np.asarray(ref, np.float64)
-    if F.shape[-1] == 2:
-        return hypervolume_2d(F, ref)
-    F = F[np.isfinite(F).all(-1)]
-    F = F[(F < ref).all(-1)]
-    if F.shape[0] == 0:
-        return 0.0
-    lo = F.min(0)
-    rng = np.random.default_rng(seed)
-    pts = rng.uniform(lo, ref, size=(n_mc, F.shape[1]))
-    dominated = np.zeros(n_mc, bool)
-    for f in F:
-        dominated |= (pts >= f).all(-1)
-    box = np.prod(ref - lo)
-    return float(box * dominated.mean())

@@ -5,8 +5,8 @@ Two backends expose the same interface:
 * **model** — the trained subQ :class:`PerfModel` (the production path;
   sub-second solving via cached GTN embeddings + batched regressor);
 * **oracle** — the analytic simulator evaluated on *CBO-estimated* inputs
-  (what a perfect compile-time model would believe), used by algorithm
-  benchmarks and tests to isolate MOO behavior from model error.
+  (what a perfect compile-time model would believe), used by tests and
+  examples to isolate MOO behavior from model error.
 
 Objectives (minimization), matching the paper's latency/cloud-cost pair:
   f1 = analytical latency (s)      — Σ over subQs at the query level

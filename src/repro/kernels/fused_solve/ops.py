@@ -33,8 +33,8 @@ from ..ws_reduce.kernel import ws_reduce_pallas
 
 __all__ = ["fused_ws_front", "SEEN_BUCKETS"]
 
-# (N bucket, m bucket, B, k, nw) signatures dispatched so far — the
-# recompilation-bound benchmarks assert this stays ≤ the bucket count.
+# (N bucket, m bucket, B, k, nw) signatures dispatched so far — at most
+# one per bucket, however the solved shapes vary.
 SEEN_BUCKETS: set = set()
 
 

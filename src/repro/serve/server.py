@@ -136,8 +136,8 @@ class ServiceTimeModel:
     charges ``flush_s(n_full) + n_cheap * cheap_s`` — pricing the very
     mechanism preemptive degradation exploits (converting full solves
     into cheap ones under pressure) instead of flattening it into a
-    size-only charge.  Calibrate all three from measured warm flush
-    windows — see ``benchmarks/bench_server.py run_scenarios``.
+    size-only charge.  The caller calibrates all three from measured
+    warm flush windows and passes them in.
 
     Worker concurrency: a fleet co-locates ``n_workers`` replicas on the
     shared host, so each replica's optimizer work runs slower than the

@@ -174,7 +174,6 @@ class TuningService:
         reuse_banks_across_variants: bool = False,
         dedupe: bool = True,
         response_cache: Optional[ResponseCache] = None,
-        jit_solve: Optional[bool] = None,
     ):
         self.model = model
         self.cfg = cfg
@@ -186,11 +185,6 @@ class TuningService:
             self._results: Optional[ResponseCache] = response_cache
         else:
             self._results = ResponseCache() if dedupe else None
-        # None = batched jitted solve whenever a model backs the service
-        # (the oracle backend keeps the sequential per-query loop — its
-        # evaluator is already one vectorized simulator call per stage).
-        # False forces the legacy sequential path for A/B comparison.
-        self.jit_solve = jit_solve
         self.last_batch = BatchStats()
         self.totals = BatchStats()     # cumulative over the service's life
 
@@ -240,15 +234,13 @@ class TuningService:
                 f"got {len(degraded)} degrade flags for {len(queries)} "
                 "queries")
         results: List[Optional[CompileTimeResult]] = [None] * len(queries)
-        use_batched = (self._model is not None
-                       and (self.jit_solve is None or self.jit_solve))
         n_solved = n_subqs = n_cheap = n_default = 0
         run: List[int] = []
 
         def flush_run() -> None:
             nonlocal n_solved, n_subqs
             if run:
-                # repro: allow[CK002] batched full solves store under the exact (non-degrade-marked) key on purpose — same contract as the direct put below; `degraded` never reaches _solve_run (degraded queries act as run barriers above)
+                # repro: allow[CK002] full solves store under the exact (non-degrade-marked) key on purpose: degraded results are minted in _tune_cheap under degrade-marked keys, and an exact hit serving a later degraded request is the intended upgrade path; `degraded` never reaches _solve_run (degraded queries act as run barriers below)
                 solved = self._solve_run(queries, per_q_weights, tenants,
                                          run, results)
                 n_solved += len(solved)
@@ -256,7 +248,7 @@ class TuningService:
                 run.clear()
 
         for qi, (q, w) in enumerate(zip(queries, per_q_weights)):
-            if use_batched and not (degraded is not None and degraded[qi]):
+            if not (degraded is not None and degraded[qi]):
                 # Batched across the run of non-degraded neighbors; any
                 # degraded query below acts as a barrier so cache traffic
                 # keeps the sequential order (and therefore stats).
@@ -271,22 +263,12 @@ class TuningService:
                 if hit is not None:
                     results[qi] = hit
                     continue
-            if degraded is not None and degraded[qi]:
-                # repro: allow[CK002] _tune_cheap stores twice by design: under the degrade-marked key AND under the exact key, so a later exact hit upgrades the degraded answer — the `degraded` dimension is deliberately absent from the exact-key store
-                results[qi], kind = self._tune_cheap(q, w, key)
-                if kind == "cheap":
-                    n_cheap += 1
-                else:
-                    n_default += 1
-                continue
-            results[qi] = compile_time_optimize(
-                q, model=self._model, weights=w, cfg=self.cfg,
-                cost=self.cost, cache=self.cache)
-            n_solved += 1
-            n_subqs += q.n_subqs
-            if self._results is not None:
-                # repro: allow[CK002] full solves store under the exact key on purpose: degraded results are minted in _tune_cheap under degrade-marked keys, and an exact hit serving a later degraded request is the intended upgrade path
-                self._results.put(key, results[qi])
+            # repro: allow[CK002] _tune_cheap stores twice by design: under the degrade-marked key AND under the exact key, so a later exact hit upgrades the degraded answer — the `degraded` dimension is deliberately absent from the exact-key store
+            results[qi], kind = self._tune_cheap(q, w, key)
+            if kind == "cheap":
+                n_cheap += 1
+            else:
+                n_default += 1
         flush_run()
         obs.count("solve.solved", n_solved)
         obs.count("solve.subqs", n_subqs)
@@ -316,18 +298,20 @@ class TuningService:
                    idxs: Sequence[int],
                    results: List[Optional[CompileTimeResult]]
                    ) -> List[int]:
-        """Jitted micro-batch solve of one run of non-degraded queries.
+        """Micro-batch solve of one run of non-degraded queries.
 
-        Semantically a transcript of the sequential loop: every
-        response-cache get/put and effective-set lookup/store happens with
-        the same keys and — per cache key — in the same order, so hit/miss
-        statistics and stored artifacts match the legacy path exactly, and
-        each result is bit-identical to its ``compile_time_optimize``
-        counterpart.  What changes is the dispatch shape: all queries'
-        stage evaluations per solver phase are fused into one bucket-padded
-        model call (:func:`fused_stage_eval`), and the HMOOC solves advance
-        in lockstep as externally-driven :class:`HmoocPlan` state machines.
-        Returns the indices actually solved (post-dedup).
+        Semantically a transcript of a per-query ``compile_time_optimize``
+        loop sharing this service's caches: every response-cache get/put
+        and effective-set lookup/store happens with the same keys and — per
+        cache key — in the same order, so hit/miss statistics and stored
+        artifacts match that loop exactly, and each result is bit-identical
+        to its ``compile_time_optimize`` counterpart.  What changes is the
+        dispatch shape: all queries' stage evaluations per solver phase are
+        fused into one bucket-padded call (:func:`fused_stage_eval`; the
+        oracle backend, ``model=None``, evaluates per request), and the
+        HMOOC solves advance in lockstep as externally-driven
+        :class:`HmoocPlan` state machines.  Returns the indices actually
+        solved (post-dedup).
         """
         model = self._model
         with obs.span("repro.solve.lookup"):
@@ -360,7 +344,8 @@ class TuningService:
                 for qi in solved:
                     pairs.extend((queries[qi], i)
                                  for i in range(queries[qi].n_subqs))
-                model.embed_many(pairs)
+                if model is not None:
+                    model.embed_many(pairs)
                 objs = {qi: StageObjectives(queries[qi], model=model,
                                             cost=self.cost) for qi in solved}
                 # -- effective-set planning --------------------------------
@@ -524,11 +509,10 @@ def tune_batch(
     cost: CostModel = DEFAULT_COST,
     cache: Optional[EffectiveSetCache] = None,
     dedupe: bool = True,
-    jit_solve: Optional[bool] = None,
 ) -> List[CompileTimeResult]:
     """One-shot batched solve; see :class:`TuningService` for a server."""
     svc = TuningService(model=model, cfg=cfg, cost=cost, cache=cache,
-                        dedupe=dedupe, jit_solve=jit_solve)
+                        dedupe=dedupe)
     return svc.tune_batch(queries, weights)
 
 

@@ -5,7 +5,6 @@ import sys
 from pathlib import Path
 
 import jax
-import pytest
 
 from repro.compile_cache import DEFAULT_CACHE_DIR, setup_compile_cache
 
@@ -45,24 +44,3 @@ def test_cache_is_written_to_env_dir(tmp_path):
     subprocess.run([sys.executable, "-c", code], env=env, check=True,
                    timeout=120)
     assert any(tmp_path.iterdir())
-
-
-def test_benchmark_runner_places_cache_and_fails_on_error(monkeypatch,
-                                                          capsys):
-    """benchmarks/run.py places the cache at start-up, keeps printing after
-    a failed benchmark and then exits non-zero."""
-    from benchmarks import bench_cluster, run
-
-    calls = []
-    monkeypatch.setattr(run, "setup_compile_cache", lambda: calls.append(1))
-
-    def boom():
-        raise RuntimeError("boom")
-    monkeypatch.setattr(bench_cluster, "run_kernels", boom)
-    monkeypatch.setattr(sys, "argv", ["run.py", "--only", "kernels,nope"])
-    with pytest.raises(SystemExit) as exc:
-        run.main()
-    assert exc.value.code == 1 and calls == [1]
-    out = capsys.readouterr().out
-    assert "=== kernels === FAILED: RuntimeError: boom" in out
-    assert "kernels: failed" in out

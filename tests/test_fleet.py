@@ -255,6 +255,29 @@ def test_router_policies():
                     if r.query.template == t}) == 1
 
 
+def test_affinity_routing_keeps_caches_warmer_than_random():
+    """Two workers on the modelled clock, one tenant per SLO class: routing
+    by template affinity reuses Algorithm-1 banks more often than random
+    routing, and hits the response cache at least as often."""
+    specs = [TenantSpec(name=slo, slo=slo, weights=w,
+                        arrivals=ArrivalModel(kind="poisson",
+                                              rate_qps=40.0 / 3))
+             for slo, w in (("strict", (0.9, 0.1)), ("degrade", (0.7, 0.3)),
+                            ("best_effort", (0.5, 0.5)))]
+    reqs = multi_tenant_stream("tpch", specs, 6, seed=0)
+    rates = {}
+    for policy in ("affinity", "random"):
+        fleet = _fleet(2, policy=policy, tenants=specs, seed=0,
+                       config=ServerConfig(max_batch=4, solve_budget_s=1.0,
+                                           clock=CLOCK))
+        fleet.serve(reqs)
+        cr = fleet.cache_report()
+        rates[policy] = (cr["effective_set"]["warm_rate"],
+                         cr["response"]["hit_rate"])
+    assert rates["affinity"][0] > rates["random"][0]
+    assert rates["affinity"][1] >= rates["random"][1]
+
+
 def test_router_assignment_is_input_order_invariant():
     """Routing happens in (arrival_s, rid) order regardless of how the
     request list is permuted: per-rid assignments never move."""

@@ -1,14 +1,14 @@
-"""Accelerator-resident jitted model-backed solve: parity + compile bounds.
+"""The batched compile-time solve: parity with the reference + compile bounds.
 
-The batched serving path (``TuningService(jit_solve=None/True)``) fuses
-every (query, subQ, candidate) stage evaluation of a micro-batch into
-bucket-padded ``PerfModel.predict_rows`` dispatches and drives the HMOOC
-solves in lockstep.  These tests pin its two contracts:
+``TuningService.tune_batch`` fuses every (query, subQ, candidate) stage
+evaluation of a micro-batch into bucket-padded ``PerfModel.predict_rows``
+dispatches (per-request evaluation on the oracle backend) and drives the
+HMOOC solves in lockstep.  These tests pin its two contracts:
 
 * **bit identity** — per-query results, cache statistics and stored
-  artifacts are exactly those of the legacy sequential path
-  (``jit_solve=False``), including dedup, template reuse, per-tenant
-  keying and degraded-query interleaving;
+  artifacts are exactly those of a per-query ``compile_time_optimize`` loop
+  over one shared effective-set cache, including dedup, template reuse,
+  per-tenant keying and degraded-query interleaving, under both backends;
 * **bounded recompilation** — across arbitrarily varying batch sizes the
   jitted functions compile at most one signature per shape bucket.
 """
@@ -17,13 +17,17 @@ import pytest
 
 from repro import obs
 from repro.core.moo.hmooc import HMOOCConfig
-from repro.core.tuning.compile_time import default_theta_result
+from repro.core.tuning.compile_time import (compile_time_optimize,
+                                            default_theta_result)
 from repro.core.tuning.objectives import StageObjectives, fused_stage_eval
+from repro.queryengine.simulator import DEFAULT_COST
 from repro.queryengine.workloads import make_benchmark, serving_stream
-from repro.serve import TuningService
+from repro.serve import EffectiveSetCache, TuningService
+from repro.serve.cache import query_fingerprint
 
 CFG = HMOOCConfig(n_c_init=16, n_clusters=4, n_p_pool=48, n_c_enrich=12,
                   max_bank=12, seed=3)
+WEIGHTS = (0.9, 0.1)
 
 
 def _assert_ct_equal(a, b):
@@ -42,28 +46,77 @@ def _stats_tuple(svc):
             s.n_default_theta)
 
 
-def test_jit_solve_bitmatches_legacy(smoke_perf_models):
+def _reference(queries, model, weights=None, tenants=None, degraded=None):
+    """Per-query ``compile_time_optimize`` over one shared effective-set cache.
+
+    The response dedup is written out from the request list: a request
+    identical to an earlier one (tenant, query, statistics, weights) takes
+    the earlier result.  A degraded request with no earlier full solve runs
+    on the template's cached banks, else on the Spark defaults.  Returns
+    ``(results, cache, stats tuple, response hits)``.
+    """
+    n = len(queries)
+    weights = weights or [WEIGHTS] * n
+    cache = EffectiveSetCache()
+    done, out = {}, []
+    solved = cheap = default = hits = 0
+    for i, q in enumerate(queries):
+        w = weights[i]
+        key = (tenants[i] if tenants else None, q.qid, query_fingerprint(q),
+               w)
+        if key in done:
+            out.append(done[key])
+            hits += 1
+        elif degraded and degraded[i]:
+            if ("degraded",) + key in done:
+                out.append(done[("degraded",) + key])
+                hits += 1
+                continue
+            peeked = cache.peek(q, CFG, model, DEFAULT_COST)
+            if peeked is None:
+                res = default_theta_result(q, model=model)
+                default += 1
+            else:
+                res = compile_time_optimize(q, model=model, weights=w,
+                                            cfg=CFG,
+                                            effective_set=peeked[0])
+                cheap += 1
+            done[key if peeked is not None and peeked[1]
+                 else ("degraded",) + key] = res
+            out.append(res)
+        else:
+            done[key] = compile_time_optimize(q, model=model, weights=w,
+                                              cfg=CFG, cache=cache)
+            out.append(done[key])
+            solved += 1
+    return out, cache, (n, solved, n - solved - cheap - default, cheap,
+                        default), hits
+
+
+@pytest.mark.parametrize("backend", ["model", "oracle"])
+def test_solve_bitmatches_reference(smoke_perf_models, backend):
     """Repeated-template stream: per-query results, response dedup and
-    effective-set reuse all match the sequential path bit for bit."""
-    model = smoke_perf_models["subq"]
+    effective-set reuse all match the per-query reference bit for bit, for
+    the model-backed service and the oracle (``model=None``) one."""
+    model = smoke_perf_models["subq"] if backend == "model" else None
     stream = serving_stream("tpch", 10, seed=5)   # repeats templates
-    legacy = TuningService(model=model, cfg=CFG, jit_solve=False)
-    jit = TuningService(model=model, cfg=CFG)
-    ra = legacy.tune_batch(stream)
-    rb = jit.tune_batch(stream)
-    for a, b in zip(ra, rb):
+    ref, cache, stats, hits = _reference(stream, model)
+    svc = TuningService(model=model, cfg=CFG)
+    got = svc.tune_batch(stream)
+    for a, b in zip(ref, got):
         _assert_ct_equal(a, b)
-    assert _stats_tuple(legacy) == _stats_tuple(jit)
-    assert legacy.cache.stats() == jit.cache.stats()
-    assert legacy._results.stats()["hits"] == jit._results.stats()["hits"]
-    # Second identical batch: both fully deduped.
-    ra2 = jit.tune_batch(stream)
-    assert jit.last_batch.n_deduped == len(stream)
-    for a, b in zip(rb, ra2):
+    assert stats[2] > 0                   # the stream does repeat requests
+    assert _stats_tuple(svc) == stats
+    assert svc.cache.stats() == cache.stats()
+    assert svc._results.stats()["hits"] == hits
+    # Second identical batch: fully deduped.
+    again = svc.tune_batch(stream)
+    assert svc.last_batch.n_deduped == len(stream)
+    for a, b in zip(got, again):
         _assert_ct_equal(a, b)
 
 
-def test_jit_solve_per_tenant_golden_determinism(smoke_perf_models):
+def test_solve_per_tenant_golden_determinism(smoke_perf_models):
     """Per-tenant keys and per-query weights survive the batched path
     unchanged: each tenant gets the pick its own weights select, identical
     to a sequential solve of the same request."""
@@ -72,35 +125,41 @@ def test_jit_solve_per_tenant_golden_determinism(smoke_perf_models):
     queries = [qs[1], qs[1], qs[5]]
     tenants = ["a", "b", "a"]
     weights = [(1.0, 0.0), (0.0, 1.0), (0.5, 0.5)]
-    legacy = TuningService(model=model, cfg=CFG, jit_solve=False)
-    jit = TuningService(model=model, cfg=CFG)
-    ra = legacy.tune_batch(queries, weights, tenants=tenants)
-    rb = jit.tune_batch(queries, weights, tenants=tenants)
-    for a, b in zip(ra, rb):
+    ref, cache, stats, _ = _reference(queries, model, weights, tenants)
+    svc = TuningService(model=model, cfg=CFG)
+    got = svc.tune_batch(queries, weights, tenants=tenants)
+    for a, b in zip(ref, got):
         _assert_ct_equal(a, b)
-    assert _stats_tuple(legacy) == _stats_tuple(jit)
+    assert _stats_tuple(svc) == stats
+    assert svc.cache.stats() == cache.stats()
     # Same front across weights, picks chosen per request's own weights.
-    np.testing.assert_array_equal(rb[0].front, rb[1].front)
-    assert rb[0].chosen_objectives[0] <= rb[1].chosen_objectives[0]
+    np.testing.assert_array_equal(got[0].front, got[1].front)
+    assert got[0].chosen_objectives[0] <= got[1].chosen_objectives[0]
 
 
-def test_jit_solve_degraded_interleave_matches_legacy(smoke_perf_models):
+@pytest.mark.parametrize("backend", ["model", "oracle"])
+@pytest.mark.parametrize("degraded", [
+    [False, True, False, False, True, False, False, False],
+    # t000-v1 on t000-v0's banks (cheap), t009-v0 after its full solve (hit)
+    [False, True, False, False, False, True, False, True],
+], ids=["defaults", "cheap-and-hit"])
+def test_solve_degraded_interleave_matches_reference(smoke_perf_models,
+                                                     backend, degraded):
     """Degraded queries act as barriers inside a batch; stats and results
-    still match the sequential transcript exactly."""
-    model = smoke_perf_models["subq"]
+    still match the per-query transcript exactly."""
+    model = smoke_perf_models["subq"] if backend == "model" else None
     stream = serving_stream("tpch", 8, seed=11)
-    degraded = [False, True, False, False, True, False, False, False]
-    legacy = TuningService(model=model, cfg=CFG, jit_solve=False)
-    jit = TuningService(model=model, cfg=CFG)
-    ra = legacy.tune_batch(stream, degraded=degraded)
-    rb = jit.tune_batch(stream, degraded=degraded)
-    for a, b in zip(ra, rb):
+    ref, cache, stats, hits = _reference(stream, model, degraded=degraded)
+    svc = TuningService(model=model, cfg=CFG)
+    got = svc.tune_batch(stream, degraded=degraded)
+    for a, b in zip(ref, got):
         _assert_ct_equal(a, b)
-    assert _stats_tuple(legacy) == _stats_tuple(jit)
-    assert legacy.cache.stats() == jit.cache.stats()
+    assert _stats_tuple(svc) == stats
+    assert svc.cache.stats() == cache.stats()
+    assert svc._results.stats()["hits"] == hits
 
 
-def test_jit_solve_recompilation_bound():
+def test_solve_recompilation_bound():
     """Across varying micro-batch sizes the jitted model functions compile
     one signature per shape bucket: the compiles counted under the model's
     dispatch span equal the buckets it used."""

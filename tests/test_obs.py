@@ -243,11 +243,9 @@ def test_results_bit_identical_under_a_profiler_trace(models, tmp_path):
             np.testing.assert_array_equal(x, y)
 
 
-@pytest.mark.parametrize("jit_solve", [None, False],
-                         ids=["batched", "sequential"])
-def test_solve_subqs_counts_the_subqs_of_solved_requests(models, jit_solve):
+def test_solve_subqs_counts_the_subqs_of_solved_requests(models):
     msub, _ = models
-    svc = TuningService(model=msub, cfg=CFG, jit_solve=jit_solve)
+    svc = TuningService(model=msub, cfg=CFG)
     a, b, c, d = (make_benchmark("tpch")[i] for i in (8, 4, 2, 6))
     assert len({a.n_subqs, b.n_subqs, c.n_subqs}) == 3
     with obs.record() as rec:

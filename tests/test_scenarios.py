@@ -340,6 +340,42 @@ def test_elastic_controller_raises_cap_under_pressure():
         _same(s.result, r)
 
 
+LOADED = scenario_matrix(n_per_tenant=4, rate_qps=7.0)
+
+
+@pytest.mark.parametrize("spec", LOADED, ids=[m.name for m in LOADED])
+def test_elastic_capacity_no_worse_than_static(spec):
+    """On a modelled clock (no host jitter), steady load at about 0.7x the
+    static cap's capacity with the scenario's peaks past it: the elastic
+    controller, allowed twice the static cap, loses no goodput, and its
+    strict-tenant p99 stays within the static one or the budget."""
+    budget = 0.3
+    clock = ServiceTimeModel(flush_points=((1, 0.05), (8, 0.2)),
+                             round_s=0.005, cheap_s=0.001)
+    sc = spec.build(seed=0)
+    common = dict(max_batch=2, solve_budget_s=budget, solve_reserve_s=0.07,
+                  clock=clock)
+
+    def serve(config):
+        srv = OptimizerServer(config=config, weights=WEIGHTS, cfg=CFG,
+                              tenants=sc.tenants)
+        served = srv.serve(sc.requests, capacity_events=sc.capacity_events)
+        return srv.latency_report(served), srv.last_run.flush_caps
+
+    st, _ = serve(ServerConfig(**common))
+    el, caps = serve(ServerConfig(
+        **common, elastic=ElasticPolicy(min_batch=2, max_batch=4,
+                                        target_delay_s=0.25 * budget)))
+    assert st["goodput"] < 1.0                  # the load overloads static
+    if spec.name.startswith("flash_crowd"):
+        assert max(caps) > 2    # the elastic cap (or a capacity event) lifts
+    assert el["goodput"] >= st["goodput"]
+    st_p99 = st["tenants"]["strict"]["plan_latency_s"]["p99"]
+    el_p99 = el["tenants"]["strict"]["plan_latency_s"]["p99"]
+    if math.isfinite(st_p99) and math.isfinite(el_p99):
+        assert el_p99 <= max(st_p99, budget)
+
+
 def test_preemptive_degradation_engages_before_deadline():
     """With elastic control and a saturated forecast, a degrade-class head
     whose budget is *not yet* blown is still routed to the cheap path when

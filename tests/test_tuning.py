@@ -30,6 +30,22 @@ def test_compile_time_beats_default(q9):
     assert ct.solve_time < 2.0      # paper's cloud constraint: 1–2 s
 
 
+def test_served_plans_beat_spark_defaults():
+    """Plan quality (paper Table 4): over the 22 TPC-H templates, the θ the
+    service picks on the oracle objective at ``HMOOCConfig()`` runs faster
+    under AQE on the simulator, on average, than the Spark defaults."""
+    from repro.serve import TuningService
+    queries = make_benchmark("tpch")
+    cts = TuningService(cfg=HMOOCConfig()).tune_batch(queries, (0.9, 0.1))
+    tc, tp, ts = default_theta(1)
+    default = [run_with_aqe(q, tc[0], tp[0], ts[0]).sim.actual_latency[0]
+               for q in queries]
+    tuned = [run_with_aqe(q, ct.theta_c, ct.theta_p0, ct.theta_s0
+                          ).sim.actual_latency[0]
+             for q, ct in zip(queries, cts)]
+    assert np.mean(tuned) < np.mean(default)
+
+
 def test_runtime_opt_no_worse(q9):
     ct = compile_time_optimize(q9, weights=(0.9, 0.1),
                                cfg=HMOOCConfig(seed=0))
