@@ -24,7 +24,8 @@ analytic simulator or synthetic functions.
 Hot paths are array-level: every stage_eval call covers a whole
 representative set or candidate population at once (m calls per phase
 instead of C·m), dominance masks route through the Pallas ``pareto_filter``
-kernel above the small-n threshold (``pareto_mask_fast``), and HMOOC2's
+kernel above the small-n threshold (``pareto_mask_fast``) and below it a
+phase's C·m banks are filtered in one vectorized pass, and HMOOC2's
 per-weight bank argmin runs on the ``ws_reduce`` kernel when enabled.
 
 The candidate-sampling half of Algorithm 1 (LHS, clustering, crossover) is
@@ -41,6 +42,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ... import obs
 from .clustering import kmeans_fit
 from . import pareto as _pareto
 from .pareto import _f32_tie_hazard, pareto_mask_fast, pareto_mask_np
@@ -143,11 +145,72 @@ def _pareto_bank(F: np.ndarray, cap: int) -> np.ndarray:
     mask = pareto_mask_fast(F)
     idx = np.nonzero(mask)[0]
     if idx.size > cap:
-        # Keep a spread: sort by first objective, take evenly spaced.
-        order = idx[np.argsort(F[idx, 0])]
-        keep = np.linspace(0, order.size - 1, cap).round().astype(int)
-        idx = order[keep]
+        idx = _spread(F, idx, cap)
     return idx
+
+
+def _spread(F: np.ndarray, idx: np.ndarray, cap: int) -> np.ndarray:
+    """``cap`` of the rows ``idx`` of F: sorted by the first objective,
+    evenly spaced."""
+    order = idx[np.argsort(F[idx, 0])]
+    keep = np.linspace(0, order.size - 1, cap).round().astype(int)
+    return order[keep]
+
+
+def _pareto_banks_2d(Fs: Sequence[np.ndarray], C: int, P: int, cap: int
+                     ) -> Tuple[List[np.ndarray], int]:
+    """:func:`_pareto_bank` of every two-objective bank, in one pass.
+
+    ``Fs`` holds m arrays of shape ``(C·P, 2)``; bank ``i·C + r`` is rows
+    ``r·P:(r+1)·P`` of ``Fs[i]``.  Returns each bank's indices, equal array
+    for array to :func:`_pareto_bank`'s when its mask takes the float64
+    path, and the number of banks over ``cap``, which take its
+    :func:`_spread`.
+
+    The mask follows :func:`~.pareto._pareto_mask_2d_np`: sorted by f0, a
+    row survives when its f1 is the least of its equal-f0 group and below
+    every f1 of a smaller f0.  A row alone in its group needs only the
+    prefix minimum; the groups of tied f0 are settled apart.
+    """
+    R = len(Fs) * C
+    if R * P == 0:
+        return [np.zeros(0, np.intp) for _ in range(R)], 0
+    F = np.stack(Fs).astype(np.float64, copy=False).reshape(R * P, 2)
+    f0, f1 = np.ascontiguousarray(F.T).reshape(2, R, P)
+    valid = np.isfinite(f0) & np.isfinite(f1)
+    if not valid.all():
+        # Invalid rows sort last with f1 = inf: they never survive nor
+        # lower a valid row's prefix minimum.
+        f0 = np.where(valid, f0, np.inf)
+        f1 = np.where(valid, f1, np.inf)
+    flat = (np.argsort(f0, axis=1) + P * np.arange(R)[:, None]).ravel()
+    s0 = f0.ravel()[flat].reshape(R, P)
+    s1 = f1.ravel()[flat].reshape(R, P)
+    prev = np.empty((R, P))          # least f1 before each sorted row
+    prev[:, 0] = np.inf
+    np.minimum.accumulate(s1[:, :-1], axis=1, out=prev[:, 1:])
+    keep = s1 < prev
+    tied = np.zeros((R, P), bool)    # same f0 as the row before
+    np.equal(s0[:, 1:], s0[:, :-1], out=tied[:, 1:])
+    if tied.any():
+        in_grp = tied.copy()
+        in_grp[:, :-1] |= tied[:, 1:]
+        t = np.flatnonzero(in_grp)
+        st = np.flatnonzero(~tied.ravel()[t])
+        g1 = s1.ravel()[t]
+        gmin = np.minimum.reduceat(g1, st)
+        gmin[gmin >= prev.ravel()[t[st]]] = np.nan
+        keep.ravel()[t] = g1 == np.repeat(gmin, np.diff(st, append=t.size))
+    surv = np.flatnonzero(keep.ravel())      # by bank, then by f0
+    n = np.bincount(surv // P, minlength=R)
+    ends = np.cumsum(n).tolist()
+    kept = np.sort(flat[surv]) % P
+    out = [kept[a:b] for a, b in zip([0] + ends[:-1], ends)]
+    big = np.flatnonzero(n > cap).tolist()
+    for b in big:
+        i, r = divmod(b, C)
+        out[b] = _spread(Fs[i][r * P:(r + 1) * P], out[b], cap)
+    return out, len(big)
 
 
 def _lhs(rng: np.random.Generator, n: int, d: int) -> np.ndarray:
@@ -237,6 +300,18 @@ def _rep_banks(Fs: Sequence[np.ndarray], eset: EffectiveSet,
     opt_idx: List[List[np.ndarray]] = [[] for _ in range(C)]
     k_obj = 2
     n_evals = 0
+    thr = _pareto._KERNEL_MIN_N
+    if thr is None:
+        thr = _pareto._default_kernel_min_n()
+    if P < thr and all(F.shape[1] == 2 for F in Fs):
+        # Every bank takes pareto_mask_fast's float64 path: filter them all
+        # at once.  Larger banks keep the per-bank kernel routing.
+        banks, n_capped = _pareto_banks_2d(Fs, C, P, cfg.max_bank)
+        obs.count("hmooc.banks.batched", len(banks))
+        obs.count("hmooc.banks.capped", n_capped)
+        for b, idx in enumerate(banks):
+            opt_idx[b % C].append(idx)
+        return opt_idx, k_obj, sum(F.shape[0] for F in Fs)
     for F in Fs:
         n_evals += F.shape[0]
         k_obj = F.shape[1]

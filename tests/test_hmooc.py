@@ -5,9 +5,11 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.moo.hmooc import (HMOOCConfig, _hmooc1_fixed_c,
+from repro import obs
+from repro.core.moo.hmooc import (EffectiveSet, HMOOCConfig, _hmooc1_fixed_c,
                                   _hmooc2_fixed_c, _hmooc3_extremes,
-                                  dag_aggregate, hmooc_solve)
+                                  _pareto_bank, _rep_banks, dag_aggregate,
+                                  hmooc_solve)
 from repro.core.moo.pareto import pareto_mask_np
 from repro.core.moo.wun import wun_select
 
@@ -100,3 +102,91 @@ def test_wun_respects_preferences():
     assert i_lat == 0 and i_cost == 2
     i_mid, _ = wun_select(F, np.array([0.5, 0.5]))
     assert i_mid == 1
+
+
+def _bank_objectives(kind, R, P, rng):
+    """(R, P, 2) objectives of R banks of one kind."""
+    if kind == "uniform":
+        return rng.random((R, P, 2))
+    if kind == "eighths":           # ties in f0 and exact duplicates
+        return np.round(rng.random((R, P, 2)) * 8) / 8
+    if kind == "f1_eighths":        # equal f1 across distinct f0
+        F = rng.random((R, P, 2))
+        F[..., 1] = np.round(F[..., 1] * 8) / 8
+        return F
+    if kind == "inf_nan":
+        F = rng.random((R, P, 2))
+        F[rng.random((R, P, 2)) < 0.1] = np.inf
+        F[rng.random((R, P, 2)) < 0.1] = np.nan
+        F[rng.random((R, P, 2)) < 0.05] = -np.inf
+        return F
+    if kind == "anticorrelated":    # whole banks on the front, over the cap
+        x = rng.random((R, P))
+        return np.stack([x, 1.0 - x], -1)
+    assert kind == "all_invalid"    # first bank all NaN, last all inf
+    F = rng.random((R, P, 2))
+    F[-1] = np.inf
+    F[0] = np.nan
+    return F
+
+
+def _per_bank(Fs, C, P, cap):
+    return [[_pareto_bank(F.reshape(C, P, F.shape[1])[r], cap) for F in Fs]
+            for r in range(C)]
+
+
+def _eset(C, P):
+    return EffectiveSet(Uc=np.zeros((C, 1)), labels=np.arange(C),
+                        reps=np.zeros((C, 1)), pool=np.zeros((P, 1)))
+
+
+_BANK_KINDS = ["uniform", "eighths", "f1_eighths", "inf_nan",
+               "anticorrelated", "all_invalid"]
+_BANK_SIZES = [(1, 1), (7, 13), (10, 48)]       # R = C·m = 1, 91, 480
+
+
+@pytest.mark.parametrize(
+    "kind, C, m, P",
+    [(k, C, m, P) for P in (256, 97) for k in _BANK_KINDS
+     for C, m in _BANK_SIZES]
+    + [("empty", C, m, 0) for C, m in _BANK_SIZES])
+def test_rep_banks_batched_matches_per_bank(kind, C, m, P):
+    """_rep_banks filters all C·m two-objective banks in one vectorized
+    pass: every bank equals _pareto_bank's, array for array and in order,
+    and the counters say how many banks the pass took and how many were
+    over the cap."""
+    rng = np.random.default_rng(
+        [_BANK_KINDS.index(kind) if kind in _BANK_KINDS else 9, C, m, P])
+    cfg = HMOOCConfig()
+    F = (np.zeros((C * m, 0, 2)) if kind == "empty"
+         else _bank_objectives(kind, C * m, P, rng))
+    Fs = [F[i * C:(i + 1) * C].reshape(C * P, 2) for i in range(m)]
+    with obs.record() as rec:
+        got, k_obj, n_evals = _rep_banks(Fs, _eset(C, P), cfg)
+    assert (k_obj, n_evals) == (2, C * m * P)
+    want = _per_bank(Fs, C, P, cfg.max_bank)
+    assert [len(b) for b in got] == [m] * C
+    for r in range(C):
+        for i in range(m):
+            assert got[r][i].dtype == want[r][i].dtype
+            np.testing.assert_array_equal(got[r][i], want[r][i])
+    capped = sum(int(pareto_mask_np(F[b]).sum()) > cfg.max_bank
+                 for b in range(C * m))
+    assert rec.counter("hmooc.banks.batched") == C * m
+    assert rec.counter("hmooc.banks.capped") == capped
+    assert (capped > 0) == (kind == "anticorrelated")
+
+
+def test_rep_banks_three_objectives_per_bank():
+    """k ≠ 2 objective sets keep the per-bank loop."""
+    rng = np.random.default_rng(4)
+    C, m, P = 3, 4, 64
+    Fs = [np.round(rng.random((C * P, 3)) * 4) / 4 for _ in range(m)]
+    with obs.record() as rec:
+        got, k_obj, _ = _rep_banks(Fs, _eset(C, P), HMOOCConfig())
+    assert k_obj == 3
+    assert rec.counter("hmooc.banks.batched") == 0
+    want = _per_bank(Fs, C, P, HMOOCConfig().max_bank)
+    for r in range(C):
+        for i in range(m):
+            np.testing.assert_array_equal(got[r][i], want[r][i])

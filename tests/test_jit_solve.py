@@ -326,3 +326,82 @@ def test_plan_builds_assign_rows_once(smoke_perf_models, monkeypatch):
     svc.tune_batch([make_benchmark("tpch")[3]])
     assert svc.last_batch.n_solved == 1
     assert len(calls) == 1
+
+
+def _per_bank_rep_banks(Fs, eset, cfg):
+    """_rep_banks as a loop of _pareto_bank over every (subQ, rep) bank."""
+    from repro.core.moo.hmooc import _pareto_bank
+    C, P = eset.reps.shape[0], eset.pool.shape[0]
+    opt_idx = [[] for _ in range(C)]
+    for F in Fs:
+        Fr = F.reshape(C, P, F.shape[1])
+        for r in range(C):
+            opt_idx[r].append(_pareto_bank(Fr[r], cfg.max_bank))
+    return opt_idx, Fs[-1].shape[1], sum(F.shape[0] for F in Fs)
+
+
+def test_served_banks_batched_match_per_bank(smoke_perf_models, monkeypatch):
+    """A model-backed served solve of a 48-subQ TPC-DS request and a TPC-H
+    request at the default config filters every bank in the vectorized
+    pass, and its banks and results equal those of the per-bank loop bit
+    for bit.  With the Pareto kernel's row threshold below the pool size
+    the banks go bank by bank through pareto_mask_fast's kernel route."""
+    from repro.core.moo import hmooc as hmooc_mod
+    from repro.core.moo import pareto as pareto_mod
+    queries = [make_benchmark("tpcds")[87], make_benchmark("tpch")[2]]
+    assert queries[0].n_subqs == 48
+    cfg = HMOOCConfig()
+
+    def solve():
+        banks = []
+        orig = hmooc_mod._rep_banks
+
+        def spy(Fs, eset, cfg):
+            out = orig(Fs, eset, cfg)
+            banks.append(out[0])
+            return out
+
+        with monkeypatch.context() as mp:
+            mp.setattr(hmooc_mod, "_rep_banks", spy)
+            svc = TuningService(model=smoke_perf_models["subq"], cfg=cfg)
+            with obs.record() as rec:
+                got = svc.tune_batch(queries)
+        assert svc.last_batch.n_solved == len(queries)
+        assert len(banks) == len(queries)
+        return got, banks, rec
+
+    def assert_same(a, b):
+        for x, y in zip(a[0], b[0]):
+            _assert_ct_equal(x, y)
+            assert x.n_evals == y.n_evals
+        for x, y in zip(a[1], b[1]):
+            for xr, yr in zip(x, y, strict=True):
+                for xi, yi in zip(xr, yr, strict=True):
+                    assert xi.dtype == yi.dtype
+                    np.testing.assert_array_equal(xi, yi)
+
+    batched = solve()
+    subqs = sum(q.n_subqs for q in queries)
+    assert batched[2].counter("solve.subqs") == subqs
+    assert batched[2].counter("hmooc.banks.batched") == \
+        cfg.n_clusters * subqs
+
+    with monkeypatch.context() as mp:
+        mp.setattr(hmooc_mod, "_rep_banks", _per_bank_rep_banks)
+        per_bank = solve()
+    assert per_bank[2].counter("hmooc.banks.batched") == 0
+    assert_same(batched, per_bank)
+
+    kernel_rows = []
+
+    def kernel(F, valid=None):      # the float64 mask, on the kernel's route
+        kernel_rows.append(F.shape[0])
+        return pareto_mod.pareto_mask_np(F, valid)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(pareto_mod, "_KERNEL_MIN_N", cfg.n_p_pool // 2)
+        mp.setattr(pareto_mod, "_pareto_mask_kernel", kernel)
+        routed = solve()
+    assert routed[2].counter("hmooc.banks.batched") == 0
+    assert kernel_rows.count(cfg.n_p_pool) > 0
+    assert_same(batched, routed)
