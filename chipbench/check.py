@@ -1,8 +1,9 @@
 """What decides a run's ``correct``.
 
 After the window has closed, a sample of its finished requests, drawn from
-the run's seed and always holding the one with the most subQs, is held to
-two references:
+the run's seed and always holding the one with the most subQs of each
+part of the configuration, is held to two references (each part's
+requests with its own models; each reading the largest over the parts):
 
 1. The plain reference of ``reference.py``, which imports nothing of the
    program, run op by op in ``jax.numpy`` float32 with every matrix product
@@ -69,16 +70,25 @@ _SIM_FIELDS = ("ana_latency", "actual_latency", "io_gb", "cost")
 
 
 def sample(served, k: int, seed: int) -> List:
-    """``k`` finished requests drawn from ``seed``, the longest among them."""
+    """``k`` finished requests drawn from ``seed``; for each benchmark
+    served (``Query.benchmark``, one per part of the configuration) the
+    request with the most subQs among them, first, longest first."""
     done = [s for s in served if s.status == "served" and s.ct is not None
             and s.result is not None]
-    if not done:
-        return []
-    longest = max(done, key=lambda s: (s.request.query.n_subqs, -s.rid))
-    rest = [s for s in done if s is not longest]
+
+    def size(s):
+        return s.request.query.n_subqs, -s.rid
+    longest: Dict[str, object] = {}
+    for s in done:
+        b = s.request.query.benchmark
+        if b not in longest or size(s) > size(longest[b]):
+            longest[b] = s
+    first = sorted(longest.values(), key=size, reverse=True)
+    rest = [s for s in done if all(s is not f for f in first)]
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0xC4EC]))
-    pick = rng.choice(len(rest), size=min(k - 1, len(rest)), replace=False)
-    return [longest] + [rest[i] for i in sorted(pick)]
+    pick = rng.choice(len(rest), size=max(0, min(k - len(first), len(rest))),
+                      replace=False)
+    return first + [rest[i] for i in sorted(pick)]
 
 
 def _col_gap(a: np.ndarray, ref: np.ndarray) -> float:

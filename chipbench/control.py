@@ -30,27 +30,29 @@ CONTROLS = ("high", "default")
 
 def read_seed(cell, seed: int, seconds: float) -> dict:
     """One window of the cell; the check's readings of the program and of
-    each control put in its place, and each one's verdict."""
+    each control put in its place, and each one's verdict.  Each part of a
+    composite configuration is read with its own models; each reading is
+    the largest over the parts."""
     from chipbench import check
-    from chipbench import reference as R
 
     obs = cell.window(cell.stream(seed, seconds), seed, None)
     prog = run.check_run(cell, obs, seed)
     sampled = check.sample(obs["served"], run.CHECK_SAMPLE, seed)
-    heads = {k: h.rows_out() for k, h in obs["heads"].items()}
+    heads = run.head_rows(obs)
     out = {"seed": seed, "n": len(obs["served"]), "program": prog}
-    for ref_name, ref in check.references(cell.params).items():
+    program = run.gaps_by_reference(cell, sampled, heads)
+    controls = {name: run.gaps_by_reference(cell, sampled, heads,
+                                            control=name)
+                for name in CONTROLS}
+    for ref_name in program:
         if ref_name != check.REFERENCE:
-            out[f"program@{ref_name}"] = dict(check.model_gaps(
-                sampled, cell.models, heads, ref, cell.stats, cell.cfg),
-                sequential_diffs=prog["sequential_diffs"])
+            out[f"program@{ref_name}"] = dict(
+                program[ref_name], sequential_diffs=prog["sequential_diffs"])
         for name in CONTROLS:
             key = name if ref_name == check.REFERENCE \
                 else f"{name}@{ref_name}"
-            out[key] = dict(check.model_gaps(
-                sampled, cell.models, heads, ref, cell.stats, cell.cfg,
-                control=(R.arith(name), cell.params)),
-                sequential_diffs=prog["sequential_diffs"])
+            out[key] = dict(controls[name][ref_name],
+                            sequential_diffs=prog["sequential_diffs"])
     out["correct"] = {k: check.verdict(out[k])[0]
                       for k in ("program", *CONTROLS)}
     return out

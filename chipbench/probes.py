@@ -25,7 +25,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import time
-from typing import Dict
+from typing import Dict, Iterable, Tuple
 
 import numpy as np
 
@@ -65,13 +65,15 @@ class Probes:
         self._patches.append((owner, attr))
         setattr(owner, attr, timed)
 
-    def install(self, server, models: Dict[str, object]) -> None:
+    def install(self, server, models: Iterable[Tuple[str, object]]) -> None:
+        """Wraps the server's layers and each (kind, model) of ``models``;
+        the counts of several models of one kind add up."""
         self._wrap(server.tuning, "tune_batch", "tune_batch",
                    lambda a, out, s: len(a[0]))
         self._wrap(server.session, "step_round", "step_round")
         self._wrap(server.session, "realize", "realize",
                    lambda a, out, s: len(out))
-        for kind, m in models.items():
+        for kind, m in models:
             self._wrap(m, "embed_many", f"embed_many.{kind}",
                        lambda a, out, s, m=m: len(m._emb_cache) - s,
                        pre=lambda m=m: len(m._emb_cache))

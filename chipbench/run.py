@@ -38,7 +38,7 @@ import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Dict, List, Optional, Tuple  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -59,20 +59,83 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+# What each part of a composite configuration brings: what sets one
+# benchmark and its trained models.  The composite file sets the service.
+PART_GROUPS = ("workload", "model", "training", "train_steps")
+SERVICE_GROUPS = ("hmooc", "server", "weights", "cost")
+
+
+def _read_config(bench: dict, name: str, root: str) -> Tuple[dict, str]:
+    conf = next((c for c in bench["configs"] if c["name"] == name), None)
+    if conf is None:
+        raise SystemExit(f"chipbench: no configuration {name!r} in "
+                         "BENCHMARK.json")
+    with open(os.path.join(root, conf["file"])) as f:
+        text = f.read()
+    return json.loads(text), text
+
+
+def load_config(bench: dict, name: str, root: str
+                ) -> Tuple[dict, str, List[dict]]:
+    """A configuration, its file's text, and its parts.
+
+    A file without ``parts`` is one part of itself.  A file with ``parts``
+    names two or more configurations of BENCHMARK.json, served by one
+    server: each part brings ``PART_GROUPS`` in its own file, whose text
+    keys its trained models (``train.trained_models``), so a composite
+    serves the models its parts' own cells trained; the composite file sets
+    the service (``SERVICE_GROUPS``).  The parts' ``model`` groups must be
+    equal (the FLOP readers read one) and their benchmarks distinct (the
+    server's models are keyed by ``Query.benchmark``).  Each part is
+    ``{"name", "cfg", "cfg_text", "benchmark"}``."""
+    cfg, text = _read_config(bench, name, root)
+    if "parts" not in cfg:
+        return cfg, text, [{"name": name, "cfg": cfg, "cfg_text": text,
+                            "benchmark": cfg["workload"]["benchmark"]}]
+    where = f"chipbench: composite configuration {name!r}"
+    if len(cfg["parts"]) < 2:
+        raise SystemExit(f"{where}: names {cfg['parts']}, not two parts or "
+                         "more")
+    own = [k for k in PART_GROUPS if k in cfg]
+    lacks = [k for k in SERVICE_GROUPS if k not in cfg]
+    if own or lacks:
+        raise SystemExit(f"{where}: holds {own} (its parts' to give) and "
+                         f"lacks {lacks} (its own to give)")
+    parts = []
+    for p in cfg["parts"]:
+        pcfg, ptext = _read_config(bench, p, root)
+        if "parts" in pcfg:
+            raise SystemExit(f"{where}: its part {p!r} is composite too")
+        parts.append({"name": p, "cfg": pcfg, "cfg_text": ptext,
+                      "benchmark": pcfg["workload"]["benchmark"]})
+    if any(p["cfg"]["model"] != parts[0]["cfg"]["model"] for p in parts):
+        raise SystemExit(f"{where}: its parts' model groups differ "
+                         f"({', '.join(p['name'] for p in parts)}); the FLOP "
+                         "readers read one model group")
+    benches = [p["benchmark"] for p in parts]
+    if len(set(benches)) != len(benches):
+        raise SystemExit(f"{where}: two parts serve one benchmark {benches}")
+    return dict(cfg, model=parts[0]["cfg"]["model"]), text, parts
+
+
 def load_cell(name: str, root: Optional[str] = None) -> dict:
-    """The cell's entries of BENCHMARK.json and its configuration and mix."""
+    """The cell's entries of BENCHMARK.json and its configuration (with its
+    parts, ``load_config``) and mix."""
     root = root or ROOT
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cell = next(w for w in bench["workloads"] if w["name"] == name)
-    conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    with open(os.path.join(root, conf["file"])) as f:
-        cfg_text = f.read()
+    cfg, cfg_text, parts = load_config(bench, cell["config"], root)
     with open(os.path.join(root, "chipbench", "traffic",
                            cell["traffic"] + ".json")) as f:
         mix = json.load(f)
-    return {"bench": bench, "cell": cell, "cfg": json.loads(cfg_text),
-            "cfg_text": cfg_text, "mix": mix}
+    names = sorted(p["name"] for p in parts)
+    if sorted(mix["parts"]) != names if "parts" in mix else len(names) > 1:
+        raise SystemExit(f"chipbench: mix {cell['traffic']!r} shares "
+                         f"{mix.get('parts')} among parts, configuration "
+                         f"{cell['config']!r} has {names}")
+    return {"bench": bench, "cell": cell, "cfg": cfg, "cfg_text": cfg_text,
+            "parts": parts, "mix": mix}
 
 
 def require_chip(chips: int):
@@ -108,12 +171,18 @@ def setup_compile_cache() -> str:
 
 
 class Cell:
-    """One configuration's served models and server, and its traffic."""
+    """One configuration's served models and server, and its traffic.
+
+    Each part (``load_config``) has its own ``subq`` and ``qs`` models,
+    ``params`` and ``stats``.  ``models[kind]`` is what the server is given:
+    the one part's ``PerfModel``, or with several parts a mapping of each
+    part's benchmark (``Query.benchmark``) to its ``PerfModel``."""
 
     def __init__(self, spec: dict):
         self.spec = spec
         self.cfg = spec["cfg"]
         self.mix = spec["mix"]
+        self.parts = [dict(p) for p in spec["parts"]]
 
     def setup(self) -> Dict[str, float]:
         import jax
@@ -123,21 +192,28 @@ class Cell:
         from repro.serve import (OptimizerServer, RuntimeSession,
                                  ServerConfig, TuningService)
 
-        from chipbench.train import trained_models
+        from chipbench.train import KINDS, trained_models
 
         cfg, mc = self.cfg, self.cfg["model"]
-        params, self.stats, secs = trained_models(cfg, self.spec["cfg_text"],
-                                                  log)
-        self.params = params
         gtn = GTNConfig(**mc["gtn"])
-        self.models = {}
-        for kind in ("subq", "qs"):
-            mcfg = ModelConfig(kind=kind, theta_dim=mc["theta_dim"][kind],
-                               gtn=gtn, hidden=tuple(mc["hidden"]),
-                               n_targets=mc["n_targets"])
-            self.models[kind] = PerfModel(
-                mcfg, params=jax.device_put(params[kind]),
-                target_stats=self.stats[kind])
+        secs: Dict[str, float] = {}
+        for part in self.parts:
+            params, stats, s = trained_models(part["cfg"], part["cfg_text"],
+                                              log)
+            secs = {k: secs.get(k, 0.0) + v for k, v in s.items()}
+            part.update(params=params, stats=stats, models={})
+            for kind in KINDS:
+                mcfg = ModelConfig(kind=kind, theta_dim=mc["theta_dim"][kind],
+                                   gtn=gtn, hidden=tuple(mc["hidden"]),
+                                   n_targets=mc["n_targets"])
+                part["models"][kind] = PerfModel(
+                    mcfg, params=jax.device_put(params[kind]),
+                    target_stats=stats[kind])
+        if len(self.parts) == 1:
+            self.models = self.parts[0]["models"]
+        else:
+            self.models = {kind: {p["benchmark"]: p["models"][kind]
+                                  for p in self.parts} for kind in KINDS}
         self.hcfg = HMOOCConfig(**cfg["hmooc"])
         self.weights = tuple(cfg["weights"])
         self.server = OptimizerServer(
@@ -151,15 +227,29 @@ class Cell:
     def stream(self, seed: int, seconds: float, *, warmup: bool = False,
                mix: Optional[dict] = None):
         from chipbench import traffic
-        return traffic.stream(self.cfg["workload"], mix or self.mix, seed,
+        return traffic.stream({p["name"]: p["cfg"]["workload"]
+                               for p in self.parts}, mix or self.mix, seed,
                               seconds, warmup=warmup)
 
     def warm(self, seed: int, seconds: float) -> None:
-        """Serve the warm-up stream, then run every regressor bucket."""
+        """Serve the warm-up stream, then run every regressor bucket through
+        every part's models.  A program that cannot serve the cell stops
+        the run here, before anything is timed."""
+        import traceback
+
         from repro.core.models import perf_model
-        self.server.serve(self.stream(seed ^ 0x3A3A3A, seconds, warmup=True))
+        stream = self.stream(seed ^ 0x3A3A3A, seconds, warmup=True)
+        try:
+            self.server.serve(stream)
+        except Exception as e:
+            log(traceback.format_exc())
+            raise SystemExit(
+                f"chipbench: the program could not serve the warm-up of "
+                f"{self.spec['cell']['name']} (parts "
+                f"{[p['name'] for p in self.parts]}): "
+                f"{type(e).__name__}: {e}") from e
         cap = perf_model._head_max_bucket()
-        for m in self.models.values():
+        for m in (m for p in self.parts for m in p["models"].values()):
             d = m.cfg.gtn.d_model
             b = perf_model.MIN_DISPATCH_ROWS
             while b <= cap:
@@ -169,14 +259,17 @@ class Cell:
                 b *= 2
 
     def window(self, stream, seed: int, trace_dir: Optional[str]):
-        """Serve the window with the probes on; returns what it observed."""
+        """Serve the window with the probes on; returns what it observed.
+        ``heads`` holds each part's ``HeadRecorder`` per kind."""
         import jax
         from chipbench.probes import HeadRecorder, Probes, count_compiles
 
         probes = Probes(annotate=trace_dir is not None)
-        probes.install(self.server, self.models)
-        heads = {k: HeadRecorder(m, seed + i)
-                 for i, (k, m) in enumerate(self.models.items())}
+        probes.install(self.server, [(k, m) for p in self.parts
+                                     for k, m in p["models"].items()])
+        heads = {p["name"]: {k: HeadRecorder(m, seed + 2 * j + i)
+                             for i, (k, m) in enumerate(p["models"].items())}
+                 for j, p in enumerate(self.parts)}
         try:
             with count_compiles() as compiles:
                 if trace_dir is not None:
@@ -194,7 +287,7 @@ class Cell:
                         jax.profiler.stop_trace()
         finally:
             probes.remove()
-            for h in heads.values():
+            for h in (h for hs in heads.values() for h in hs.values()):
                 h.remove()
         return {"served": served, "wall_s": wall, "t0": t0,
                 "probes": probes, "heads": heads,
@@ -248,25 +341,67 @@ def read_per_layer(bench: dict, cell: str, run: dict) -> Dict[str, dict]:
     return out
 
 
+def head_rows(obs: dict) -> Dict[str, dict]:
+    """The kept regressor rows of the window, per part and kind."""
+    return {name: {k: h.rows_out() for k, h in hs.items()}
+            for name, hs in obs["heads"].items()}
+
+
+def _of_part(sampled, part: dict) -> list:
+    return [s for s in sampled
+            if s.request.query.benchmark == part["benchmark"]]
+
+
+def gaps_by_reference(cell: Cell, sampled, heads: Dict[str, dict],
+                      control: Optional[str] = None
+                      ) -> Dict[str, Dict[str, float]]:
+    """``check.model_gaps`` against each of ``check.references``: each part's
+    sampled requests with its own models, parameters, stats and head rows;
+    each reading the largest over the parts (a NaN wins).  With
+    ``control`` (a ``reference.arith`` name) the reference computed that
+    way stands in the program's place."""
+    from chipbench import check
+    from chipbench import reference as R
+    out: Dict[str, Dict[str, float]] = {}
+    for p in cell.parts:
+        mine = _of_part(sampled, p)
+        if not mine:
+            continue
+        ctl = None if control is None else (R.arith(control), p["params"])
+        for name, ref in check.references(p["params"]).items():
+            got = check.model_gaps(mine, p["models"], heads[p["name"]], ref,
+                                   p["stats"], cell.cfg, control=ctl)
+            worst = out.setdefault(name, got)
+            for k, v in got.items():
+                if v != v or v > worst[k]:
+                    worst[k] = v
+    return out
+
+
 def check_run(cell: Cell, obs: dict, seed: int) -> Dict[str, float]:
     from chipbench import check
     sampled = check.sample(obs["served"], CHECK_SAMPLE, seed)
-    heads = {k: h.rows_out() for k, h in obs["heads"].items()}
-    refs = check.references(cell.params)
-    readings = check.model_gaps(sampled, cell.models, heads,
-                                refs[check.REFERENCE], cell.stats, cell.cfg)
-    for name, ref in refs.items():
+    heads = head_rows(obs)
+    by_ref = gaps_by_reference(cell, sampled, heads)
+    readings = by_ref.get(check.REFERENCE, {})
+    for name, got in by_ref.items():
         if name != check.REFERENCE:
-            log(f"[check] against {name}: " + json.dumps(check.model_gaps(
-                sampled, cell.models, heads, ref, cell.stats, cell.cfg)))
-    n_bad, bad = check.sequential_diffs(sampled[:SEQUENTIAL_SAMPLE],
-                                        cell.models, cell.hcfg, cell.weights)
+            log(f"[check] against {name}: " + json.dumps(got))
+    n_bad, bad = 0, []
+    for p in cell.parts:
+        mine = _of_part(sampled[:SEQUENTIAL_SAMPLE], p)
+        if mine:
+            n, lines = check.sequential_diffs(mine, p["models"], cell.hcfg,
+                                              cell.weights)
+            n_bad, bad = n_bad + n, bad + lines
     for line in bad:
         log(f"[check] differs from the sequential path: {line}")
     readings["sequential_diffs"] = n_bad
+    rows = {name: {k: 0 if v is None else len(v[0]) for k, v in h.items()}
+            for name, h in heads.items()}
     log(f"[check] {len(sampled)} requests held to the reference, "
         f"{min(len(sampled), SEQUENTIAL_SAMPLE)} to the sequential path; "
-        f"head rows {({k: (0 if v is None else len(v[0])) for k, v in heads.items()})}")
+        f"head rows {rows}")
     return readings
 
 

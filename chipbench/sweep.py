@@ -9,7 +9,9 @@ Prints one JSON line per rate: the latency percentiles, the served rate,
 and the drain (last finish minus last arrival on the server's clock),
 which grows with the window once a backlog builds.  The knee is the
 highest rate whose ``solve_p95_s`` stays within the solve budget with no
-growing drain; capacity is the plateau of ``served_qps``.
+growing drain; capacity is the plateau of ``served_qps``.  A composite configuration's
+parts are resolved by name (``run.load_cell``), and the rate is the whole
+stream's, shared among the parts as the mix says.
 """
 from __future__ import annotations
 

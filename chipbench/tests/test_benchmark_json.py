@@ -49,13 +49,24 @@ def test_names_and_units(bench):
 
 def test_files_found_by_name(bench):
     used = {w["config"] for w in bench["workloads"]}
-    assert used == {c["name"] for c in bench["configs"]}
+    files = {}
     for c in bench["configs"]:
         assert c["file"].startswith("chipbench/configs/")
-        assert os.path.exists(os.path.join(ROOT, c["file"]))
+        with open(os.path.join(ROOT, c["file"])) as f:
+            files[c["name"]] = json.load(f)
+    # A composite configuration names its parts, each a configuration of
+    # its own with a file of its own and no parts.
+    parts = {n: cfg.get("parts", [n]) for n, cfg in files.items()}
+    for name, names in parts.items():
+        assert all(p in files and "parts" not in files[p] for p in names), \
+            name
+    assert used == set(files)
     for w in bench["workloads"]:
-        assert os.path.exists(os.path.join(
-            ROOT, "chipbench", "traffic", w["traffic"] + ".json"))
+        with open(os.path.join(ROOT, "chipbench", "traffic",
+                               w["traffic"] + ".json")) as f:
+            mix = json.load(f)
+        assert sorted(mix.get("parts", parts[w["config"]])) == \
+            sorted(parts[w["config"]]), w["name"]
         assert w["chips"] == 1
     for m in bench["per_layer"]:
         assert os.path.exists(os.path.join(

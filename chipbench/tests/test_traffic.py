@@ -1,5 +1,6 @@
 """The traffic generator: deterministic per seed, the same work for every
-seed, and a fresh mix never repeats a (template, variant) within a run."""
+seed, and a fresh mix never repeats a (part, template, variant) within a
+run."""
 import collections
 import json
 import os
@@ -19,13 +20,19 @@ with open(os.path.join(os.path.dirname(CB), "BENCHMARK.json")) as _f:
 
 
 def _load(mix):
+    """The workload group of each part of the mix's configuration (one for
+    a configuration without parts), and the mix."""
     config, m = MIXES[mix]
     with open(os.path.join(CB, "configs", config + ".json")) as f:
-        wl = json.load(f)["workload"]
+        cfg = json.load(f)
+    wls = {}
+    for part in cfg.get("parts", [config]):
+        with open(os.path.join(CB, "configs", part + ".json")) as f:
+            wls[part] = json.load(f)["workload"]
     if isinstance(m, str):
         with open(os.path.join(CB, "traffic", m + ".json")) as f:
             m = json.load(f)
-    return wl, m
+    return wls, m
 
 
 def _key(reqs):
@@ -34,24 +41,25 @@ def _key(reqs):
 
 @pytest.mark.parametrize("mix", MIXES)
 def test_deterministic_per_seed(mix):
-    wl, m = _load(mix)
-    a = traffic.stream(wl, m, BIG_SEED, 3.0)
-    b = traffic.stream(wl, m, BIG_SEED, 3.0)
+    wls, m = _load(mix)
+    a = traffic.stream(wls, m, BIG_SEED, 3.0)
+    b = traffic.stream(wls, m, BIG_SEED, 3.0)
     assert _key(a) == _key(b)
     assert [r.rid for r in a] == list(range(len(a)))
     assert np.all(np.diff([r.arrival_s for r in a]) >= 0)
     assert a[-1].arrival_s == pytest.approx(len(a) / m["rate_qps"])
     assert len(a) == max(1, round(m["rate_qps"] * 3.0))
-    c = traffic.stream(wl, m, BIG_SEED + 1, 3.0)
+    c = traffic.stream(wls, m, BIG_SEED + 1, 3.0)
     assert _key(c) != _key(a)
 
 
 @pytest.mark.parametrize("mix", MIXES)
 def test_same_work_for_every_seed(mix):
     """Seeds reorder one fixed set of templates and gaps."""
-    wl, m = _load(mix)
-    runs = [traffic.stream(wl, m, s, 3.0) for s in (1, 77, BIG_SEED)]
-    templates = [sorted(r.query.template for r in reqs) for reqs in runs]
+    wls, m = _load(mix)
+    runs = [traffic.stream(wls, m, s, 3.0) for s in (1, 77, BIG_SEED)]
+    templates = [sorted((r.query.benchmark, r.query.template)
+                        for r in reqs) for reqs in runs]
     gaps = [sorted(np.diff([0.0] + [r.arrival_s for r in reqs]).round(12))
             for reqs in runs]
     subqs = [sorted(r.query.n_subqs for r in reqs) for reqs in runs]
@@ -62,19 +70,25 @@ def test_same_work_for_every_seed(mix):
 
 @pytest.mark.parametrize("mix", [m for m in MIXES if "fresh" in m])
 def test_fresh_never_repeats_a_pair(mix):
-    wl, m = _load(mix)
+    wls, m = _load(mix)
     for seed in (5, BIG_SEED):
-        warm = traffic.stream(wl, m, seed ^ 0x3A3A3A, 3.0, warmup=True)
-        win = traffic.stream(wl, m, seed, 3.0)
+        warm = traffic.stream(wls, m, seed ^ 0x3A3A3A, 3.0, warmup=True)
+        win = traffic.stream(wls, m, seed, 3.0)
         qids = [r.query.qid for r in warm + win]
         assert len(set(qids)) == len(qids)
 
 
 @pytest.mark.parametrize("mix", MIXES)
 def test_fresh_mix_is_uniform_over_templates(mix):
-    wl, m = _load(mix)
-    reqs = traffic.stream(wl, m, 9, wl["n_templates"] / m["rate_qps"] * 2)
-    counts = collections.Counter(r.query.template for r in reqs)
-    assert set(counts) == set(range(wl["n_templates"]))
-    assert max(counts.values()) - min(counts.values()) <= 1
-
+    """Within each part, every template equally often (to one)."""
+    wls, m = _load(mix)
+    shares = m.get("parts", {p: 1 for p in wls})
+    total = sum(shares.values())
+    seconds = max(wl["n_templates"] * total / shares[p] for p, wl
+                  in wls.items()) / m["rate_qps"] * 2
+    reqs = traffic.stream(wls, m, 9, seconds)
+    for wl in wls.values():
+        counts = collections.Counter(r.query.template for r in reqs
+                                     if r.query.benchmark == wl["benchmark"])
+        assert set(counts) == set(range(wl["n_templates"]))
+        assert max(counts.values()) - min(counts.values()) <= 1
