@@ -167,7 +167,7 @@ def fused_stage_eval(items: Sequence[StageRequest]) -> List[np.ndarray]:
     are identical to calling ``obj.stage_eval(i, Tc, Tps)`` one by one:
     row j of a padded batch equals row j of the per-request call, and the
     cost arithmetic is element-wise.  All requests must share one model
-    (the serving layer batches per service); the oracle backend
+    (the serving layer calls it once per model); the oracle backend
     (``model is None``) falls back to per-request evaluation, which is
     already one vectorized simulator call each.
     """

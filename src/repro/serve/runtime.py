@@ -67,7 +67,6 @@ import numpy as np
 
 from .. import obs
 from ..core.models.features import contention_gamma
-from ..core.models.perf_model import PerfModel
 from ..core.tuning.compile_time import CompileTimeResult
 from ..core.tuning.runtime import (RuntimeOptimizerBackend, fusion_key,
                                    score_requests, stage_pressure,
@@ -79,6 +78,7 @@ from ..queryengine.simulator import (CostModel, DEFAULT_COST, SubQSim,
                                      join_decision_stats,
                                      simulate_stage_rows, stage_stats_batch)
 from .cache import CandidatePoolCache
+from .service import Models, model_of
 
 __all__ = ["RuntimeSession", "RuntimeSessionStats", "CandidatePoolCache"]
 
@@ -132,8 +132,8 @@ class RuntimeSession:
     def __init__(
         self,
         *,
-        model_subq: Optional[PerfModel] = None,
-        model_qs: Optional[PerfModel] = None,
+        model_subq: Models = None,
+        model_qs: Models = None,
         weights: Tuple[float, float] = (0.9, 0.1),
         n_candidates: int = 64,
         cost: CostModel = DEFAULT_COST,
@@ -142,7 +142,14 @@ class RuntimeSession:
         pool_cache: Optional[CandidatePoolCache] = None,
         gamma_mode: str = "structural",
     ):
-        """``gamma_mode`` controls the §4.3 contention features the model
+        """``model_subq`` / ``model_qs`` are the L̄QP and QS models: each
+        one :class:`PerfModel` that serves every benchmark (the default;
+        ``None`` is the oracle), or a ``{benchmark: PerfModel}`` mapping,
+        under which :meth:`admit` gives each query's backend the models of
+        its own ``Query.benchmark`` (:func:`~repro.serve.service.model_of`;
+        a benchmark a mapping lacks raises).
+
+        ``gamma_mode`` controls the §4.3 contention features the model
         backends consume (the oracle backend ignores γ entirely):
 
         * ``"structural"`` (default) — per-stage γ from the query's own
@@ -203,14 +210,16 @@ class RuntimeSession:
         scope-independent).
         """
         w = tuple(weights) if weights is not None else tuple(self.weights)
-        has_model = self.model_subq is not None or self.model_qs is not None
+        model_subq = model_of(self.model_subq, query)
+        model_qs = model_of(self.model_qs, query)
+        has_model = model_subq is not None or model_qs is not None
         gamma = None                                  # backend auto/none
         if self.gamma_mode == "off":
             gamma = np.zeros((query.n_subqs, 4), np.float64)
         backend = RuntimeOptimizerBackend(
             query, ct.theta_c, seed_theta_p=ct.theta_p_sub,
-            seed_theta_s=ct.theta_s_sub, model_subq=self.model_subq,
-            model_qs=self.model_qs, weights=w,
+            seed_theta_s=ct.theta_s_sub, model_subq=model_subq,
+            model_qs=model_qs, weights=w,
             cost=self.cost,
             pools=self.pool_cache.get(self.seed, self.n_candidates,
                                       scope=pool_scope),
@@ -263,6 +272,8 @@ class RuntimeSession:
             for e, cand, j in zip(waiting, cands, picks):
                 self._step(e, cand[j])
         obs.count("runtime.requests", len(waiting))
+        for e in waiting:
+            obs.count(f"runtime.requests.{e.query.benchmark}")
         return len(waiting)
 
     def _live_gamma(self, e: _Entry, sq_id: int) -> np.ndarray:

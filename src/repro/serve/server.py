@@ -88,14 +88,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import obs
-from ..core.models.perf_model import PerfModel
 from ..core.moo.hmooc import HMOOCConfig
 from ..core.tuning.compile_time import CompileTimeResult
 from ..queryengine.aqe import AQEResult
 from ..queryengine.workloads import StreamRequest, TenantSpec
 from .admission import ElasticController, ElasticPolicy, TenantScheduler
 from .runtime import RuntimeSession
-from .service import TuningService
+from .service import Models, TuningService
 
 __all__ = ["OptimizerServer", "ServerConfig", "ServedQuery", "ServerStats",
            "ServiceTimeModel", "jain_index", "REJECTED_STATUSES"]
@@ -333,7 +332,7 @@ class OptimizerServer:
         config: ServerConfig = ServerConfig(),
         weights: Optional[Weights] = None,
         cfg: Optional[HMOOCConfig] = None,
-        model: Optional[PerfModel] = None,
+        model: Models = None,
         tuning: Optional[TuningService] = None,
         session: Optional[RuntimeSession] = None,
         tenants: Sequence[TenantSpec] = (),
@@ -341,9 +340,10 @@ class OptimizerServer:
         """``weights`` parameterizes the default-built session and is the
         fallback preference for tenants that configure none; ``cfg`` and
         ``model`` parameterize the default-built *compile-time* service
-        (``model`` is the §5.1 subQ objective model; the default session
-        stays on the oracle runtime backend).  For model-backed runtime
-        re-scoring pass a prebuilt ``session`` with
+        (``model`` is the §5.1 subQ objective model, one ``PerfModel`` or a
+        ``{benchmark: PerfModel}`` mapping as :class:`TuningService` takes;
+        the default session stays on the oracle runtime backend).  For
+        model-backed runtime re-scoring pass a prebuilt ``session`` with
         ``model_subq``/``model_qs`` set; prebuilt ``tuning``/``session``
         objects also share caches across servers.  ``tenants`` registers
         per-tenant admission policy (weights, share, priority, budget);

@@ -16,12 +16,18 @@ Every returned :class:`CompileTimeResult` is bit-identical to what a
 standalone ``compile_time_optimize`` call would produce for that query
 (dedup shares exact results; cache reuse is exact for identical queries and
 disabled across variants unless explicitly opted in).
+
+One service may serve several benchmarks, each with its own model: its
+``model`` is then a ``{benchmark: PerfModel}`` mapping, and every query is
+solved with the model of its own ``Query.benchmark`` (:func:`model_of`).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
-from typing import List, Optional, Sequence, Tuple, Union
+from typing import (List, Mapping, Optional, Sequence, Tuple, TypeVar,
+                    Union)
 
 import numpy as np
 
@@ -37,9 +43,34 @@ from ..queryengine.simulator import CostModel, DEFAULT_COST
 from .cache import (EffectiveSetCache, model_fingerprint, pack_snapshot,
                     query_fingerprint, template_key, unpack_snapshot)
 
-__all__ = ["TuningService", "tune_batch", "ResponseCache"]
+__all__ = ["TuningService", "tune_batch", "ResponseCache", "model_of",
+           "Models"]
 
 Weights = Tuple[float, float]
+
+# What a service is given as its model: one model (or ``None``, the
+# oracle) for every benchmark, or one per benchmark.
+Models = Union[None, PerfModel, Mapping[str, Optional[PerfModel]]]
+_M = TypeVar("_M")
+
+
+def model_of(models: Union[_M, Mapping[str, _M]], query: Query) -> _M:
+    """The entry of ``models`` that serves ``query``.
+
+    A mapping is looked up by ``query.benchmark``; anything else (one
+    model, ``None`` for the oracle, or a value derived from one such as its
+    fingerprint) serves every benchmark.  A benchmark the mapping lacks is
+    an error: no query is ever solved with another benchmark's model.
+    """
+    if not isinstance(models, Mapping):
+        return models
+    try:
+        return models[query.benchmark]
+    except KeyError:
+        raise KeyError(
+            f"no model for benchmark {query.benchmark!r} (query "
+            f"{query.qid!r}); the models are keyed by "
+            f"{sorted(models)}") from None
 
 
 @dataclasses.dataclass
@@ -162,12 +193,21 @@ class _CheapEntry:
 
 
 class TuningService:
-    """Long-lived compile-time tuning server with an effective-set cache."""
+    """Long-lived compile-time tuning server with an effective-set cache.
+
+    ``model`` is the subQ objective model: one :class:`PerfModel` that
+    serves every benchmark (the default; ``None`` is the analytic oracle),
+    or a ``{benchmark: PerfModel}`` mapping, under which each query is
+    solved, cached and keyed with the model of its own ``Query.benchmark``
+    (:func:`model_of`; a benchmark the mapping lacks raises).  Either way
+    each result is bit-identical to ``compile_time_optimize`` with that
+    query's own model, whatever the mix of benchmarks in a batch.
+    """
 
     def __init__(
         self,
         *,
-        model: Optional[PerfModel] = None,
+        model: Models = None,
         cfg: HMOOCConfig = HMOOCConfig(),
         cost: CostModel = DEFAULT_COST,
         cache: Optional[EffectiveSetCache] = None,
@@ -189,16 +229,21 @@ class TuningService:
         self.totals = BatchStats()     # cumulative over the service's life
 
     @property
-    def model(self) -> Optional[PerfModel]:
+    def model(self) -> Models:
         return self._model
 
     @model.setter
-    def model(self, m: Optional[PerfModel]) -> None:
+    def model(self, m: Models) -> None:
         # Response-cache keys carry the fingerprint of the model that
         # produced them, so swapping in a retrained model invalidates old
-        # entries by key mismatch alone.
+        # entries by key mismatch alone; under a mapping, only the entries
+        # of the benchmarks whose model changed.
+        if isinstance(m, Mapping):
+            m = dict(m)
+            self._model_fp = {b: model_fingerprint(x) for b, x in m.items()}
+        else:
+            self._model_fp = model_fingerprint(m)
         self._model = m
-        self._model_fp = model_fingerprint(m)
 
     def tune_batch(
         self,
@@ -234,17 +279,20 @@ class TuningService:
                 f"got {len(degraded)} degrade flags for {len(queries)} "
                 "queries")
         results: List[Optional[CompileTimeResult]] = [None] * len(queries)
-        n_solved = n_subqs = n_cheap = n_default = 0
+        n_cheap = n_default = 0
+        # Requests and subQs solved, per benchmark.
+        solved_by: collections.Counter = collections.Counter()
+        subqs_by: collections.Counter = collections.Counter()
         run: List[int] = []
 
         def flush_run() -> None:
-            nonlocal n_solved, n_subqs
             if run:
                 # repro: allow[CK002] full solves store under the exact (non-degrade-marked) key on purpose: degraded results are minted in _tune_cheap under degrade-marked keys, and an exact hit serving a later degraded request is the intended upgrade path; `degraded` never reaches _solve_run (degraded queries act as run barriers below)
                 solved = self._solve_run(queries, per_q_weights, tenants,
                                          run, results)
-                n_solved += len(solved)
-                n_subqs += sum(queries[qi].n_subqs for qi in solved)
+                for qi in solved:
+                    solved_by[queries[qi].benchmark] += 1
+                    subqs_by[queries[qi].benchmark] += queries[qi].n_subqs
                 run.clear()
 
         for qi, (q, w) in enumerate(zip(queries, per_q_weights)):
@@ -270,8 +318,12 @@ class TuningService:
             else:
                 n_default += 1
         flush_run()
+        n_solved = sum(solved_by.values())
         obs.count("solve.solved", n_solved)
-        obs.count("solve.subqs", n_subqs)
+        obs.count("solve.subqs", sum(subqs_by.values()))
+        for b in solved_by:
+            obs.count(f"solve.solved.{b}", solved_by[b])
+            obs.count(f"solve.subqs.{b}", subqs_by[b])
         dt = time.perf_counter() - t0
         self.last_batch = BatchStats(
             n_queries=len(queries), n_solved=n_solved,
@@ -288,9 +340,10 @@ class TuningService:
         # across distinct queries in a long-lived service.  cfg/cost/model
         # fingerprint complete the inputs the solver reads, so one
         # ResponseCache can be shared across differently-configured
-        # services and survives model reloads (see ResponseCache).
+        # services and survives model reloads (see ResponseCache).  The
+        # fingerprint is that of the query's own benchmark's model.
         return (tenant, q.qid, query_fingerprint(q), w, self.cfg, self.cost,
-                self._model_fp)
+                model_of(self._model_fp, q))
 
     def _solve_run(self, queries: Sequence[Query],
                    per_q_weights: Sequence[Weights],
@@ -307,13 +360,14 @@ class TuningService:
         artifacts match that loop exactly, and each result is bit-identical
         to its ``compile_time_optimize`` counterpart.  What changes is the
         dispatch shape: all queries' stage evaluations per solver phase are
-        fused into one bucket-padded call (:func:`fused_stage_eval`; the
-        oracle backend, ``model=None``, evaluates per request), and the
+        fused into one bucket-padded call per model (:func:`fused_stage_eval`;
+        the oracle backend, ``model=None``, evaluates per request), and the
         HMOOC solves advance in lockstep as externally-driven
-        :class:`HmoocPlan` state machines.  Returns the indices actually
-        solved (post-dedup).
+        :class:`HmoocPlan` state machines.  Counters: ``solve.phases``, the
+        lockstep phases; ``solve.model_groups``, the ``fused_stage_eval``
+        calls over them (one per model with rows in a phase).  Returns the
+        indices actually solved (post-dedup).
         """
-        model = self._model
         with obs.span("repro.solve.lookup"):
             # -- response planning: dedup within and across batches --------
             keys: dict = {}
@@ -339,14 +393,15 @@ class TuningService:
                     pending[key] = qi
                 solved.append(qi)
             if solved:
-                # -- embedding prefetch: one GTN dispatch for the whole run
-                pairs = []
-                for qi in solved:
-                    pairs.extend((queries[qi], i)
-                                 for i in range(queries[qi].n_subqs))
-                if model is not None:
-                    model.embed_many(pairs)
-                objs = {qi: StageObjectives(queries[qi], model=model,
+                models = {qi: model_of(self._model, queries[qi])
+                          for qi in solved}
+                # -- embedding prefetch: one GTN dispatch per model --------
+                for model, group in _by_model(solved, models.get):
+                    if model is not None:
+                        model.embed_many(
+                            [(queries[qi], i) for qi in group
+                             for i in range(queries[qi].n_subqs)])
+                objs = {qi: StageObjectives(queries[qi], model=models[qi],
                                             cost=self.cost) for qi in solved}
                 # -- effective-set planning --------------------------------
                 t0s: dict = {}
@@ -355,7 +410,7 @@ class TuningService:
                 pending_eset: dict = {}  # template key -> (owner qi, owner fp)
                 waiting: List[Tuple[int, int]] = []   # (qi, owner qi)
                 for qi in solved:
-                    q, obj = queries[qi], objs[qi]
+                    q, obj, model = queries[qi], objs[qi], models[qi]
                     t0s[qi] = time.perf_counter()
                     tk = template_key(q, self.cfg, model, self.cost)
                     fp = query_fingerprint(q)
@@ -389,7 +444,7 @@ class TuningService:
                     if not plans[qi].reused_banks:
                         pending_eset[tk] = (qi, fp)
         if solved:
-            # -- lockstep rounds: one fused model call per solver phase ----
+            # -- lockstep rounds: fused model calls per solver phase -------
             while True:
                 active = [qi for qi in solved
                           if qi in plans and not plans[qi].done]
@@ -402,7 +457,17 @@ class TuningService:
                         items.extend((objs[qi], i, rows)
                                      for i, rows in reqs)
                         spans.append((qi, len(reqs)))
-                    evals = fused_stage_eval(items)
+                    # One fused call per model, in first-appearance order;
+                    # the results go back in the rows' order.
+                    groups = _by_model(range(len(items)),
+                                       lambda j: items[j][0].model)
+                    evals: list = [None] * len(items)
+                    for _, group in groups:
+                        for j, ev in zip(group, fused_stage_eval(
+                                [items[j] for j in group])):
+                            evals[j] = ev
+                    obs.count("solve.phases")
+                    obs.count("solve.model_groups", len(groups))
                 # Feed by phase: Algorithm-1 bank builds, then the assign
                 # phase's HMOOC aggregation (each plan's feed reads only its
                 # own state, so the order across plans is free).
@@ -438,13 +503,13 @@ class TuningService:
                         # Stats-only replay of the lookup the sequential
                         # path would have issued here (after the owner's
                         # store).
-                        self.cache.lookup(q, self.cfg, model, self.cost)
+                        self.cache.lookup(q, self.cfg, models[qi], self.cost)
                     plan = plans[qi]
                     res = plan.result
                     if not plan.reused_banks and \
                             res.effective_set is not None:
                         self.cache.store(q, self.cfg, res.effective_set,
-                                         model, self.cost)
+                                         models[qi], self.cost)
                     ct = finish_result(q, objs[qi], res, w, t0s[qi])
                     results[qi] = ct
                     if self._results is not None:
@@ -468,7 +533,8 @@ class TuningService:
         effective-set cache evicted (or gained) the template between the
         store and the hit.
         """
-        peeked = self.cache.peek(q, self.cfg, self._model, self.cost)
+        model = model_of(self._model, q)
+        peeked = self.cache.peek(q, self.cfg, model, self.cost)
         if peeked is not None and peeked[1]:
             # Exact bank reuse is bit-identical to a full solve: share the
             # exact key with the full-quality path.
@@ -477,7 +543,7 @@ class TuningService:
                 if hit is not None:
                     return hit, "cheap"
             res = compile_time_optimize(
-                q, model=self._model, weights=w, cfg=self.cfg,
+                q, model=model, weights=w, cfg=self.cfg,
                 cost=self.cost, effective_set=peeked[0])
             if self._results is not None:
                 self._results.put(exact_key, res)
@@ -489,11 +555,11 @@ class TuningService:
                 return hit.result, hit.kind
         if peeked is not None:
             res = compile_time_optimize(
-                q, model=self._model, weights=w, cfg=self.cfg,
+                q, model=model, weights=w, cfg=self.cfg,
                 cost=self.cost, effective_set=peeked[0])
             kind = "cheap"
         else:
-            res = default_theta_result(q, model=self._model, cost=self.cost)
+            res = default_theta_result(q, model=model, cost=self.cost)
             kind = "default"
         if self._results is not None:
             self._results.put(key, _CheapEntry(res, kind))
@@ -505,7 +571,7 @@ def tune_batch(
     weights: Union[Weights, Sequence[Weights]] = (0.9, 0.1),
     cfg: HMOOCConfig = HMOOCConfig(),
     *,
-    model: Optional[PerfModel] = None,
+    model: Models = None,
     cost: CostModel = DEFAULT_COST,
     cache: Optional[EffectiveSetCache] = None,
     dedupe: bool = True,
@@ -514,6 +580,21 @@ def tune_batch(
     svc = TuningService(model=model, cfg=cfg, cost=cost, cache=cache,
                         dedupe=dedupe)
     return svc.tune_batch(queries, weights)
+
+
+def _by_model(idxs, model_at) -> List[Tuple[object, List[int]]]:
+    """``idxs`` grouped by the model ``model_at`` gives each (by identity),
+    in first-appearance order: ``[(model, [idx, ...]), ...]``."""
+    groups: List[Tuple[object, List[int]]] = []
+    for i in idxs:
+        m = model_at(i)
+        for gm, members in groups:
+            if gm is m:
+                members.append(i)
+                break
+        else:
+            groups.append((m, [i]))
+    return groups
 
 
 def _expand_weights(weights, n: int) -> List[Weights]:
